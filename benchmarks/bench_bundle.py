@@ -49,6 +49,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.utils.compile_cache import enable_compile_cache
 from repro.core import PCDNConfig, make_problem, solve
 from repro.core import bundles as B
 from repro.core.direction import delta_decrement, newton_direction
@@ -264,6 +265,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes (CI); headline keys still written")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         n, s_grid, s_scale, nnz_fix = 512, [1024, 4096], [1024, 4096], 8

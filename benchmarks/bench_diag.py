@@ -41,6 +41,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
 
 import jax
 
+from repro.utils.compile_cache import enable_compile_cache
 from repro.core import PCDNConfig, make_problem, solve
 from repro.data.synthetic import make_classification
 from repro.diag import report as diag_report
@@ -199,6 +200,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes (CI)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         s, n, P, iters, repeats = 400, 300, 64, 10, 3

@@ -20,7 +20,8 @@ the same full-set KKT tolerance:
                   on the mesh (the flagship config)
 
 Runs on 8 forced host devices (mesh (2, 4) data x model) so it exercises
-the real collective schedule; set XLA_FLAGS yourself to override.
+the real collective schedule; set XLA_FLAGS yourself to override. It
+refuses to run on an accelerator backend (run it with JAX_PLATFORMS=cpu).
 
 Writes BENCH_engine.json at the repo root and a copy under
 benchmarks/results/.
@@ -38,12 +39,14 @@ import time         # noqa: E402
 import numpy as np  # noqa: E402
 import jax          # noqa: E402
 
+from repro.utils.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import PCDNConfig                       # noqa: E402
 from repro.core.sharded import solve_sharded            # noqa: E402
 from repro.data import make_classification              # noqa: E402
 from repro.engine import (ShardedBackend,               # noqa: E402
                           ShardedPCDNConfig)
 from repro.path import PathConfig, run_path             # noqa: E402
+from repro.launch.mesh import make_mesh                  # noqa: E402
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RESULTS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -55,13 +58,20 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes + short grid (CI)")
     args = ap.parse_args(argv)
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"bench_engine measures a (2, 4) mesh of forced host devices; "
+            f"this process found {jax.default_backend()} devices, on "
+            f"which that mesh is not what it would measure. Run it with "
+            f"JAX_PLATFORMS=cpu.")
+    enable_compile_cache()
 
     if args.smoke:
         s, n, P_local, n_points, span, max_outer = 600, 1024, 16, 5, 30.0, 300
     else:
         s, n, P_local, n_points, span, max_outer = 2000, 4096, 32, 12, 100.0, 600
     tol = 1e-3
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     X, y, _ = make_classification(s, n, sparsity=0.99, corr=0.2, seed=1)
 
     scfg = ShardedPCDNConfig(P_local=P_local, c=1.0, tol_kkt=tol,

@@ -40,6 +40,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
 
 import jax
 
+from repro.utils.compile_cache import enable_compile_cache
 from repro.core import PCDNConfig, make_problem
 from repro.data.synthetic import make_classification
 from repro.engine import LocalBackend
@@ -182,6 +183,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes (CI)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         s, n, P, iters, repeats = 400, 300, 64, 20, 3
@@ -190,9 +192,12 @@ def main(argv=None):
         s, n, P, iters, repeats = 2000, 2000, 256, 40, 5
         points, sweep_P, sweep_outer = 6, 64, 25
 
-    ckpt_row = bench_checkpoint(s, n, P, iters, every=10, repeats=repeats)
+    # the recovery arm's CLI children need the device, and a process
+    # that has run JAX work holds it: run them before this process
+    # computes anything
     recovery_row = bench_recovery("a9a", points, sweep_P, sweep_outer,
                                   tol=1e-3)
+    ckpt_row = bench_checkpoint(s, n, P, iters, every=10, repeats=repeats)
 
     payload = {
         "backend": jax.default_backend(),

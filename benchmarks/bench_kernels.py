@@ -39,6 +39,7 @@ import time
 import numpy as np
 
 from benchmarks.common import RESULTS_DIR, emit
+from repro.utils.compile_cache import enable_compile_cache
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HEADLINE = os.path.join(REPO_ROOT, "BENCH_kernels.json")
@@ -350,6 +351,7 @@ def main(argv=None):
     ap.add_argument("--no-persist", action="store_true",
                     help="do not write winners into the autotune cache")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.kernels import autotune
     repeats = args.repeats or (2 if args.smoke else 5)

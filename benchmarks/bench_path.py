@@ -53,6 +53,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.utils.compile_cache import enable_compile_cache
 from repro.core import PCDNConfig, make_problem, pcdn, solve
 from repro.data import make_classification
 from repro.path import PathConfig, c_grid, run_path, solve_batch
@@ -222,6 +223,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes + short grid (CI)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         s, n, P, n_points, span, batch = 600, 2048, 128, 6, 30.0, 4

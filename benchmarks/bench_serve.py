@@ -42,6 +42,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
 import jax
 import jax.numpy as jnp
 
+from repro.utils.compile_cache import enable_compile_cache
 from repro.core.design_matrix import PaddedCSCDesign
 from repro.kernels import ops
 from repro.serve.batcher import MicroBatcher
@@ -205,6 +206,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes (CI)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         K, n = 8, 8192

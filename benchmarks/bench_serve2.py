@@ -43,6 +43,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
 
 import jax
 
+from repro.utils.compile_cache import enable_compile_cache
 from repro.serve.artifact import ModelArtifact, ModelFamily
 from repro.serve.loop import ServeLoop, drive_poisson
 from repro.serve.predict import scorer_cache_sizes
@@ -334,6 +335,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes / short drives (CI)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # a single-core box timeshares the load generator and the scheduler
     # under the GIL; the default 5ms switch interval would add +-10ms of

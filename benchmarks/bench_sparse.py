@@ -28,6 +28,7 @@ import numpy as np
 
 import jax
 
+from repro.utils.compile_cache import enable_compile_cache
 from repro.core import PCDNConfig, make_problem, solve
 from repro.core.pcdn import make_outer_iteration
 from repro.data import make_classification, make_sparse_classification
@@ -140,6 +141,7 @@ def main(argv=None):
     ap.add_argument("--no-big", action="store_true",
                     help="skip the 20k x 50k sparse-only run")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.quick:
         s, n, P = 1024, 2048, 128

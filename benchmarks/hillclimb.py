@@ -27,6 +27,8 @@ os.environ.setdefault("XLA_FLAGS",
 import argparse
 import json
 
+from repro.utils.compile_cache import enable_compile_cache
+
 RESULTS = os.path.join(os.path.dirname(__file__), "results", "hillclimb")
 
 
@@ -94,6 +96,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes, no cache writes")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.cell in ("kernel", "all"):
         cell_kernel(smoke=args.smoke)
     if args.cell in ("ladder", "all"):

@@ -12,6 +12,8 @@ import sys
 import time
 import traceback
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
@@ -20,6 +22,7 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated module suffixes to run")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     quick = not args.full
 
     from benchmarks import (fig1_iterations_vs_P, fig2_time_vs_P,

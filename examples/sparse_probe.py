@@ -20,6 +20,7 @@ from repro.core import PCDNConfig, make_problem, solve
 from repro.data.synthetic import train_accuracy
 from repro.models.transformer import Model
 from repro.launch.specs import train_batch_specs
+from repro.launch.mesh import make_mesh
 
 
 def main(argv=None):
@@ -30,7 +31,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     cfg = get_config(args.arch, reduced=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = Model(cfg, mesh)
     params = model.init_params(jax.random.PRNGKey(0))
 

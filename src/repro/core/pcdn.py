@@ -239,6 +239,17 @@ def make_bundle_step(problem: L1Problem, cfg: PCDNConfig):
     return step
 
 
+def jit_over_problem(fn: Callable, problem: L1Problem) -> Callable:
+    """`fn(problem, *args)` jitted with the problem as an ARGUMENT and
+    bound with functools.partial -> a callable of `*args`.
+
+    A jitted closure over the problem would embed its arrays (the whole
+    design matrix) in the executable as constants: on a v5e that made
+    the gisette outer step a 534 MB executable, too large for the
+    persistent compilation cache, recompiled by every process."""
+    return functools.partial(jax.jit(fn), problem)
+
+
 def make_outer_iteration(problem: L1Problem, cfg: PCDNConfig):
     """Legacy static-c outer iteration (all b bundles) + diagnostics.
 
@@ -247,19 +258,17 @@ def make_outer_iteration(problem: L1Problem, cfg: PCDNConfig):
     engine layer instead: `repro.engine.local.LocalBackend` wraps
     `make_path_outer`, whose traced-c contract subsumes this one.
     """
-    n = problem.n_features
-    step = make_bundle_step(problem, cfg)
-
-    def outer(w: Array, z: Array, key: Array):
+    def outer(problem: L1Problem, w: Array, z: Array, key: Array):
+        step = make_bundle_step(problem, cfg)
         key, sub = jax.random.split(key)
-        idxs = B.partition(sub, n, cfg.P)                  # (b, P)
+        idxs = B.partition(sub, problem.n_features, cfg.P)  # (b, P)
         (w, z), (steps, alphas) = jax.lax.scan(step, (w, z), idxs)
         f = problem.objective_from_margins(z, w)           # incl. l2 term
         kkt = problem.kkt_violation(w, z)
         nnz = jnp.sum(w != 0)
         return w, z, key, f, kkt, nnz, jnp.mean(steps.astype(jnp.float32))
 
-    return jax.jit(outer)
+    return jit_over_problem(outer, problem)
 
 
 def make_path_outer(problem: L1Problem, cfg: PCDNConfig):
@@ -302,10 +311,9 @@ def make_path_outer(problem: L1Problem, cfg: PCDNConfig):
     the engine dispatches extras by structure (tuple vs bare array), so
     both flags compose.
     """
-    n = problem.n_features
-
-    def outer(w: Array, z: Array, key: Array, active: Array,
-              recheck: Array, c: Array):
+    def outer(problem: L1Problem, w: Array, z: Array, key: Array,
+              active: Array, recheck: Array, c: Array):
+        n = problem.n_features
         prob = problem.with_c(c)
         step = make_bundle_step(prob, cfg)
         key, sub = jax.random.split(key)
@@ -358,7 +366,7 @@ def make_path_outer(problem: L1Problem, cfg: PCDNConfig):
             base = base + (viol,)
         return base
 
-    return jax.jit(outer)
+    return jit_over_problem(outer, problem)
 
 
 def solve(problem: L1Problem, cfg: PCDNConfig,

@@ -91,6 +91,25 @@ def csr_to_padded_csc(csr: CSRMatrix,
     return PaddedCSC(col_rows=col_rows, col_vals=col_vals, shape=(s, n))
 
 
+def padded_csc_to_csr(X: PaddedCSC) -> CSRMatrix:
+    """Padded-CSC -> CSR without densifying. Entries that share a (row,
+    column) slot — `make_sparse_classification` draws rows with
+    replacement — are summed into one, the value X @ w gives them."""
+    s, n = X.shape
+    mask = X.col_rows < s
+    cols = np.broadcast_to(np.arange(n, dtype=np.int64)[:, None],
+                           mask.shape)[mask]
+    key = X.col_rows[mask].astype(np.int64) * n + cols
+    uniq, inv = np.unique(key, return_inverse=True)
+    data = np.bincount(inv, weights=X.col_vals[mask].astype(np.float64),
+                       minlength=uniq.shape[0]).astype(np.float32)
+    rows = uniq // n
+    indptr = np.concatenate(
+        [[0], np.cumsum(np.bincount(rows, minlength=s))]).astype(np.int64)
+    return CSRMatrix(data=data, indices=(uniq % n).astype(np.int32),
+                     indptr=indptr, shape=(s, n))
+
+
 def _parse_libsvm_text(path: str):
     # Two flat 1-D token lists (not an (nnz, 2) unicode matrix — numpy
     # fixed-width string arrays cost max-token-width * 4 B per cell,
@@ -185,9 +204,14 @@ def load_libsvm(path: str, n_features: Optional[int] = None,
     return X, y, classes
 
 
-def save_libsvm(path: str, X: np.ndarray, y: np.ndarray) -> None:
+def save_libsvm(path: str, X, y: np.ndarray) -> None:
+    """Write (X, y) as LIBSVM text. X: a dense (s, n) array or a
+    CSRMatrix, which is written as it is, never densified."""
+    csr = X if isinstance(X, CSRMatrix) else CSRMatrix.from_dense(X)
+    ptr = csr.indptr
     with open(path, "w") as fh:
-        for xi, yi in zip(X, y):
-            nz = np.nonzero(xi)[0]
-            feats = " ".join(f"{j + 1}:{xi[j]:.6g}" for j in nz)
+        for i, yi in enumerate(y):
+            cols = csr.indices[ptr[i]:ptr[i + 1]]
+            vals = csr.data[ptr[i]:ptr[i + 1]]
+            feats = " ".join(f"{j + 1}:{v:.6g}" for j, v in zip(cols, vals))
             fh.write(f"{yi:g} {feats}\n")

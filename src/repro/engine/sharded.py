@@ -60,7 +60,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils.compat import shard_map as _shard_map
 
 from repro.core import bundles as B
 from repro.core.design_matrix import padded_row_support
@@ -497,10 +496,10 @@ def make_sharded_outer(cfg: ShardedPCDNConfig, mesh: Mesh,
         # viol is (n_local,) per model shard, replicated over data axes
         out_specs = out_specs + (P(model_axis),)
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         outer_local, mesh=mesh,
         in_specs=in_specs,
-        out_specs=out_specs,
+        out_specs=out_specs, check_vma=False,
     )
 
     def outer(*args):
@@ -540,8 +539,8 @@ def make_sharded_margins(cfg: ShardedPCDNConfig, mesh: Mesh, s_local: int,
     else:
         in_specs = (P(model_axis, dspec), P(model_axis, dspec),
                     P(model_axis))
-    mapped = _shard_map(margins_local, mesh=mesh, in_specs=in_specs,
-                        out_specs=P(dspec))
+    mapped = jax.shard_map(margins_local, mesh=mesh, in_specs=in_specs,
+                           out_specs=P(dspec), check_vma=False)
     return jax.jit(mapped)
 
 

@@ -35,6 +35,7 @@ import jax
 import numpy as np
 
 from repro.fault.checkpoint import CheckpointManager
+from repro.launch.mesh import make_mesh
 
 
 @dataclasses.dataclass
@@ -146,4 +147,4 @@ class ElasticMeshProvider:
         while model > 1 and n % model != 0:
             model //= 2  # degrade TP gracefully if devices were lost
         data = n // model
-        return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((data, model), ("data", "model"))

@@ -88,8 +88,8 @@ SEARCH_SPACES: Dict[str, Dict[str, Tuple[object, ...]]] = {
     },
     "pcdn_sparse_direction": {
         "impl": ("pallas", "xla"),
-        "block_p": (32, 64, 128, 256),
-        "block_k": (None, 64, 256),
+        "block_p": (128, 256),      # lane axis of the outputs: 128-multiples
+        "block_k": (None, 128, 256),
     },
     "pcdn_linesearch": {
         "impl": ("pallas", "xla"),
@@ -97,7 +97,7 @@ SEARCH_SPACES: Dict[str, Dict[str, Tuple[object, ...]]] = {
     },
     "serve_margins_dense": {
         "impl": ("pallas", "xla"),
-        "block_b": (32, 64, 128, 256),
+        "block_b": (128, 256),      # lane axis of the output rows
         "block_a": (None, 128, 512),
     },
     "serve_margins_csc": {

@@ -22,8 +22,9 @@ Pallas route).
 
 Interpreter mode is controlled by the ``REPRO_KERNELS_INTERPRET`` env
 var: "auto" (default) runs compiled kernels on TPU and the interpreter
-everywhere else, "1"/"true" forces the interpreter, "0"/"false" forces
-compiled kernels. Resolution is lazy (first kernel trace), so importing
+on the CPU (tests), and refuses any other backend; "1"/"true" forces the
+interpreter, "0"/"false" forces compiled kernels. Resolution is lazy
+(first kernel trace), so importing
 this module never initializes a jax backend and no import-order-
 sensitive monkeypatching is needed on real TPU. Assigning the legacy
 ``repro.kernels.ops.INTERPRET = False`` still works: a non-None value
@@ -46,7 +47,7 @@ from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.pcdn_bundle import pcdn_bundle_kernel
 from repro.kernels.pcdn_direction import pcdn_direction_kernel
 from repro.kernels.pcdn_linesearch import pcdn_linesearch_kernel
-from repro.kernels.pcdn_margin import (serve_margins_csc_kernel,
+from repro.kernels.pcdn_margin import (BLOCK_E, serve_margins_csc_kernel,
                                        serve_margins_dense_kernel)
 from repro.kernels.pcdn_sparse_direction import pcdn_sparse_direction_kernel
 
@@ -64,25 +65,44 @@ def interpret_mode() -> bool:
         env = os.environ.get("REPRO_KERNELS_INTERPRET", "auto")
         env = env.strip().lower()
         if env in ("auto", ""):
-            INTERPRET = jax.default_backend() != "tpu"
+            backend = jax.default_backend()
+            if backend not in ("tpu", "cpu"):
+                raise RuntimeError(
+                    f"the Pallas kernels compile for TPU and are "
+                    f"interpreted on the CPU; backend {backend!r} has "
+                    f"neither (set REPRO_KERNELS_INTERPRET=1 to interpret)")
+            INTERPRET = backend == "cpu"
         else:
             INTERPRET = env not in ("0", "false", "no", "off")
     return INTERPRET
 
 
-def _launch_span(kernel: str, impl: str):
+def _launch_span(kernel: str, impl: str, x: Array):
     """Per-launch trace span (DESIGN.md section 13.3) for EAGER kernel
-    dispatches only. Inside an outer jit trace `trace_state_clean()` is
-    False and host timing would measure tracing, not execution — the
-    span is suppressed there (the enclosing engine/serve span already
-    covers the compiled program). Eager spans measure dispatch; the
-    serving and benchmark callers block right after, so nesting under
-    their spans stays proper."""
+    dispatches only. Inside an outer jit trace the arguments are tracers
+    and host timing would measure tracing, not execution — the span is
+    suppressed there (the enclosing engine/serve span already covers the
+    compiled program). Eager spans measure dispatch; the serving and
+    benchmark callers block right after, so nesting under their spans
+    stays proper."""
     if (obs.metrics_enabled() or obs.trace_enabled()) \
-            and jax.core.trace_state_clean():
+            and not isinstance(x, jax.core.Tracer):
         obs.inc(f"kernels.{kernel}.launches")
         return obs.span(f"kernels.{kernel}", "kernels", args={"impl": impl})
     return obs.trace._NULL_SPAN
+
+
+def _lane_tile(block: int | None, size: int) -> int:
+    """Tile along an axis that is the lane (last) axis of some block.
+
+    The TPU compiler takes a block dim that is a multiple of 128 lanes or
+    the whole padded extent: `block` covering the 8-padded `size` (or
+    None) gives that whole extent, anything smaller is rounded up to the
+    next multiple of 128."""
+    full = -(-size // 8) * 8
+    if block is None or block >= full:
+        return full
+    return min(-(-int(block) // 128) * 128, full)
 
 
 def _pad_to(x: Array, axis: int, multiple: int, value=0.0) -> Array:
@@ -128,7 +148,7 @@ def pcdn_direction(XB: Array, u: Array, v: Array, w_B: Array,
     cfg = autotune.resolve(
         "pcdn_direction", autotune.shape_bucket(s=s, p=P), XB.dtype,
         {"impl": impl, "block_s": block_s, "block_p": block_p})
-    with _launch_span("pcdn_direction", cfg["impl"]):
+    with _launch_span("pcdn_direction", cfg["impl"], XB):
         if cfg["impl"] == "xla":
             return _direction_xla(XB, u, v, w_B, l2=l2)
         return _direction_pallas(XB, u, v, w_B, l2=l2,
@@ -145,18 +165,19 @@ def pcdn_direction(XB: Array, u: Array, v: Array, w_B: Array,
 def _sparse_direction_pallas(rows: Array, vals: Array, u: Array, v: Array,
                              w_B: Array, l2: float, block_p: int,
                              block_k: int | None):
-    """Pads P to a tile multiple; padded features carry sentinel rows
-    (gather fills 0) and w = 0, so g = 0 -> d = 0, and are sliced away.
-    The k axis is padded inside the raw launch when tiled."""
-    P, _ = rows.shape
-    s = u.shape[0]
-    bp = min(block_p, max(8, P))
-    rowsp = _pad_to(rows, 0, bp, value=s)
-    valsp = _pad_to(vals, 0, bp)
+    """Gathers ug = u[rows], vg = v[rows] in XLA (sentinel rows fill 0),
+    then pads P to the tile: padded features carry zero factors and
+    w = 0, so g = 0 -> d = 0, and are sliced away."""
+    P, K = rows.shape
+    bp = _lane_tile(block_p, P)
+    ug = jnp.take(u.astype(jnp.float32), rows, mode="fill", fill_value=0)
+    vg = jnp.take(v.astype(jnp.float32), rows, mode="fill", fill_value=0)
+    ugp, vgp, valsp = (_pad_to(a, 0, bp) for a in (ug, vg, vals))
     wp = _pad_to(w_B, 0, bp)
-    d, g, h = pcdn_sparse_direction_kernel(rowsp, valsp, u, v, wp, l2=l2,
-                                           block_p=bp, block_k=block_k,
-                                           interpret=interpret_mode())
+    d, g, h = pcdn_sparse_direction_kernel(
+        ugp, vgp, valsp, wp, l2=l2, block_p=bp,
+        block_k=None if block_k is None else _lane_tile(block_k, K),
+        interpret=interpret_mode())
     return d[:P], g[:P], h[:P]
 
 
@@ -180,7 +201,7 @@ def pcdn_sparse_direction(rows: Array, vals: Array, u: Array, v: Array,
         "pcdn_sparse_direction", autotune.shape_bucket(p=P, k=K, s=s),
         vals.dtype,
         {"impl": impl, "block_p": block_p, "block_k": block_k})
-    with _launch_span("pcdn_sparse_direction", cfg["impl"]):
+    with _launch_span("pcdn_sparse_direction", cfg["impl"], vals):
         if cfg["impl"] == "xla":
             return _sparse_direction_xla(rows, vals, u, v, w_B, l2=l2)
         return _sparse_direction_pallas(rows, vals, u, v, w_B, l2=l2,
@@ -218,7 +239,7 @@ def pcdn_linesearch(z: Array, delta: Array, y: Array, alphas: Array,
     cfg = autotune.resolve(
         "pcdn_linesearch", autotune.shape_bucket(s=s, q=alphas.shape[0]),
         z.dtype, {"impl": impl, "block_s": block_s})
-    with _launch_span("pcdn_linesearch", cfg["impl"]):
+    with _launch_span("pcdn_linesearch", cfg["impl"], z):
         if cfg["impl"] == "xla":
             return _linesearch_xla(z, delta, y, alphas, kind=kind)
         return _linesearch_pallas(z, delta, y, alphas, kind=kind,
@@ -234,22 +255,23 @@ def pcdn_linesearch(z: Array, delta: Array, y: Array, alphas: Array,
 def _bundle_pallas(vals: Array, pos: Array, z_R: Array, y_R: Array,
                    w_B: Array, alphas: Array, c, kind: str, l2: float,
                    sigma: float, gamma: float, block_q: int | None):
-    """Pads P and r_max to lane multiples: padded features carry vals = 0
-    and w = 0 (d = 0, no l1/Delta contribution), padded support slots
-    z = 0 / y = 1 / delta = 0 (loss delta exactly 0). pos is NOT
-    re-targeted — padded slab entries keep pointing at real slots with
-    value 0."""
+    """Scatters the slab into its support-dense form xr[j, pos_jk] +=
+    vals_jk (and x2r with vals^2) in XLA, padding P to 8 and r_max to
+    128: padded features carry xr = 0 and w = 0 (d = 0, no l1/Delta
+    contribution), padded support slots z = 0 / y = 1 / delta = 0 (loss
+    delta exactly 0)."""
     P, _ = vals.shape
     R = z_R.shape[0]
-    valsp = _pad_to(vals, 0, 8)
-    posp = _pad_to(pos, 0, 8, value=0)
-    wp = _pad_to(w_B, 0, 8)
-    zp = _pad_to(z_R, 0, 128)
-    yp = _pad_to(y_R, 0, 128, value=1.0)
+    Pp, Rp = -(-P // 8) * 8, -(-R // 128) * 128
+    v32 = vals.astype(jnp.float32)
+    cols = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[:, None],
+                            pos.shape)
+    xr = jnp.zeros((Pp, Rp), jnp.float32).at[cols, pos].add(v32)
+    x2r = jnp.zeros((Pp, Rp), jnp.float32).at[cols, pos].add(v32 * v32)
     upd_w, upd_z, alpha, q = pcdn_bundle_kernel(
-        valsp, posp, zp, yp, wp, alphas, c, kind=kind, l2=l2,
-        sigma=sigma, gamma=gamma, block_q=block_q,
-        interpret=interpret_mode())
+        xr, x2r, _pad_to(z_R, 0, 128), _pad_to(y_R, 0, 128, value=1.0),
+        _pad_to(w_B, 0, 8), alphas, c, kind=kind, l2=l2, sigma=sigma,
+        gamma=gamma, block_q=block_q, interpret=interpret_mode())
     return upd_w[:P], upd_z[:R], alpha, q
 
 
@@ -272,16 +294,17 @@ def pcdn_bundle(vals: Array, pos: Array, z_R: Array, y_R: Array,
     n_steps) with upd_* pre-scaled by the accepted alpha — the caller
     only scatters them at the bundle indices / support rows.
 
-    The default single-program launch keeps the whole (Q, r_max)
-    candidate grid in VMEM (~2M f32 cap); a tuned block_q tiles the
-    candidate axis and lifts that cap (kernels/pcdn_bundle).
+    The launch holds the support-dense slab (P, r_max) in VMEM; the
+    default single program also keeps the whole (Q, r_max) candidate
+    grid there, and a tuned block_q tiles the candidate axis
+    (kernels/pcdn_bundle).
     """
     P, K = vals.shape
     cfg = autotune.resolve(
         "pcdn_bundle",
         autotune.shape_bucket(p=P, k=K, r=z_R.shape[0], q=alphas.shape[0]),
         vals.dtype, {"impl": impl, "block_q": block_q})
-    with _launch_span("pcdn_bundle", cfg["impl"]):
+    with _launch_span("pcdn_bundle", cfg["impl"], vals):
         if cfg["impl"] == "xla":
             return _bundle_xla(vals, pos, z_R, y_R, w_B, alphas, c,
                                kind=kind, l2=l2, sigma=sigma, gamma=gamma)
@@ -297,13 +320,18 @@ def pcdn_bundle(vals: Array, pos: Array, z_R: Array, y_R: Array,
 @functools.partial(jax.jit, static_argnames=("block_b", "block_a"))
 def _margins_dense_pallas(X: Array, idx: Array, val: Array, block_b: int,
                           block_a: int | None) -> Array:
-    """Pads B to a tile multiple with zero rows (margins sliced away)."""
+    """Gathers xg = X[:, idx] in XLA (sentinel columns fill 0) as
+    (K, B, A), padding B with zero rows (margins sliced away) and A with
+    zero values."""
     B, _ = X.shape
-    bb = min(block_b, max(8, B))
-    Xp = _pad_to(X, 0, bb)
-    z = serve_margins_dense_kernel(Xp, idx, val, block_b=bb,
-                                   block_a=block_a,
-                                   interpret=interpret_mode())
+    K, A = idx.shape
+    bb = _lane_tile(block_b, B)
+    ba = _lane_tile(block_a, A)
+    xg = jnp.take(_pad_to(X, 0, bb), idx.reshape(-1), axis=1, mode="fill",
+                  fill_value=0)
+    xg = _pad_to(xg.reshape(-1, K, A).transpose(1, 0, 2), 2, ba)
+    z = serve_margins_dense_kernel(xg, _pad_to(val, 1, ba), block_b=bb,
+                                   block_a=ba, interpret=interpret_mode())
     return z[:B]
 
 
@@ -324,7 +352,7 @@ def serve_margins_dense(X: Array, idx: Array, val: Array,
     cfg = autotune.resolve(
         "serve_margins_dense", autotune.shape_bucket(b=B, n=n, k=K, a=A),
         val.dtype, {"impl": impl, "block_b": block_b, "block_a": block_a})
-    with _launch_span("serve_margins_dense", cfg["impl"]):
+    with _launch_span("serve_margins_dense", cfg["impl"], X):
         if cfg["impl"] == "xla":
             return _margins_dense_xla(X, idx, val)
         return _margins_dense_pallas(X, idx, val, block_b=cfg["block_b"],
@@ -334,8 +362,19 @@ def serve_margins_dense(X: Array, idx: Array, val: Array,
 @functools.partial(jax.jit, static_argnames=("n_requests",))
 def _margins_csc_pallas(col_rows: Array, col_vals: Array, idx: Array,
                         val: Array, n_requests: int) -> Array:
-    return serve_margins_csc_kernel(col_rows, col_vals, idx, val,
-                                    n_requests=n_requests,
+    """Gathers each model's active request columns in XLA: sentinel
+    models fill row id n_requests (matches no request) and value 0."""
+    K, A = idx.shape
+    rows = jnp.take(col_rows, idx, axis=0, mode="fill",
+                    fill_value=n_requests)                 # (K, A, k_max)
+    vals = jnp.take(col_vals.astype(jnp.float32), idx, axis=0,
+                    mode="fill", fill_value=0.0) * \
+        val.astype(jnp.float32)[:, :, None]
+    rows, vals = rows.reshape(K, -1), vals.reshape(K, -1)
+    if rows.shape[1] > BLOCK_E:
+        rows = _pad_to(rows, 1, BLOCK_E, value=n_requests)
+        vals = _pad_to(vals, 1, BLOCK_E)
+    return serve_margins_csc_kernel(rows, vals, n_requests=n_requests,
                                     interpret=interpret_mode())
 
 
@@ -350,8 +389,7 @@ def serve_margins_csc(col_rows: Array, col_vals: Array, idx: Array,
 
     col_rows/col_vals (n, k_max) feature-major request layout (sentinel
     row id == n_requests), idx/val (K, A) -> (n_requests, K) float32.
-    No padding needed: the grid is over models and the scatter output is
-    already request-shaped. col_vals/val may be bf16 storage.
+    col_vals/val may be bf16 storage.
     """
     n, k_max = col_rows.shape
     K, A = idx.shape
@@ -359,7 +397,7 @@ def serve_margins_csc(col_rows: Array, col_vals: Array, idx: Array,
         "serve_margins_csc",
         autotune.shape_bucket(n=n, kmax=k_max, k=K, a=A, b=n_requests),
         val.dtype, {"impl": impl})
-    with _launch_span("serve_margins_csc", cfg["impl"]):
+    with _launch_span("serve_margins_csc", cfg["impl"], col_vals):
         if cfg["impl"] == "xla":
             return _margins_csc_xla(col_rows, col_vals, idx, val,
                                     n_requests=n_requests)

@@ -92,6 +92,10 @@ def add_solver_args(ap: argparse.ArgumentParser):
     ap.add_argument("--tol", type=float, default=1e-3)
     ap.add_argument("--max-outer", type=int, default=100)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--scale", type=float, default=None,
+                    help="size of a profile --dataset relative to its "
+                         "published shape (1.0 = published; default: the "
+                         "CPU-budget shape)")
     ap.add_argument("--shrink", action="store_true",
                     help="active-set shrinking (DESIGN.md section 8.2; "
                          "both backends)")
@@ -250,7 +254,7 @@ def load_dataset(args, with_test: bool = False):
     backend interplay: a padded_csc file load stays CSR for the sharded
     placer (which re-packs per shard) and pre-packs padded-CSC locally.
     """
-    scale = getattr(args, "scale", None)
+    scale = args.scale
     if os.path.exists(args.dataset):
         if args.layout == "padded_csc":
             file_layout = "csr" if args.backend == "sharded" \

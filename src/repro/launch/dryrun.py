@@ -38,7 +38,6 @@ from repro.models.transformer import Model
 from repro.optim.adamw import AdamWConfig, adamw_abstract_state
 from repro.train.steps import (_batch_spec, cache_specs, make_serve_step,
                                make_train_step, opt_state_specs)
-from repro.utils import compat
 from repro.utils import hlo as hlo_util
 from repro.utils import hlo_cost
 
@@ -132,7 +131,7 @@ def lower_cell(arch: str, cell_name: str, multi_pod: bool,
     t_compile = time.perf_counter() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    raw_cost = compat.cost_analysis(compiled)
+    raw_cost = compiled.cost_analysis()
     hlo_text = compiled.as_text()
     # trip-count-aware accounting (XLA's cost_analysis counts while bodies
     # once — see utils/hlo_cost.py). All numbers below are PER DEVICE: the

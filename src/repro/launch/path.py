@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from repro.utils.compile_cache import enable_compile_cache
 from repro.core.problem import validation_accuracy
 from repro.launch import common
 from repro.serve import artifact as art
@@ -59,8 +60,6 @@ def main(argv=None):
     ap.add_argument("--mode", default="sweep", choices=["sweep", "batch"],
                     help="sweep: sequential warm-started path; batch: "
                          "solve all grid points at once via vmap")
-    ap.add_argument("--scale", type=float, default=None,
-                    help="paper_like size scale (None = CPU-budget shape)")
     ap.add_argument("--val-frac", type=float, default=0.2,
                     help="held-out row fraction for the best-c pick "
                          "(profile datasets; 0 disables)")
@@ -77,6 +76,7 @@ def main(argv=None):
     common.add_diag_args(ap)
     common.add_fault_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.mode == "batch" and (args.ckpt_dir or args.resume):
         ap.error("--ckpt-dir/--resume require --mode sweep (the lockstep "
                  "batch engine solves all points at once — there is no "

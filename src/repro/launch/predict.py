@@ -35,6 +35,7 @@ import time
 
 import numpy as np
 
+from repro.utils.compile_cache import enable_compile_cache
 from repro.data import load_libsvm, paper_like
 from repro.data.libsvm import CSRMatrix
 from repro.serve.artifact import ModelFamily, load_model, pick_best_c
@@ -160,8 +161,13 @@ def _run_serve(args, family) -> dict:
         print(f"[serve] hot-swap -> version "
               f"{swap_state['ticket'].version}, response versions "
               f"{versions}, recompiles={recompiles}")
+    if results:
+        # served margins in submit order (arrivals cycle the first n_req
+        # rows), for checking against from-scratch scoring
+        payload["margins"] = np.stack([r.margins for r in results]).tolist()
     if y_raw is not None and drive["rejects"] == 0 and results \
-            and bank.kind == "binary" and not args.swap_model:
+            and not drive["failed"] and bank.kind == "binary" \
+            and not args.swap_model:
         preds = decide(bank, np.stack([r.margins for r in results]))
         # arrivals cycle the first n_req rows in submit order
         sel = np.arange(len(results)) % n_req
@@ -171,9 +177,9 @@ def _run_serve(args, family) -> dict:
           f"{drive['offered_rps']:.0f} rps offered: "
           f"p50={1e3 * (drive['p50_s'] or 0):.2f}ms "
           f"p99={1e3 * (drive['p99_s'] or 0):.2f}ms "
-          f"rejects={drive['rejects']} "
+          f"rejects={drive['rejects']} failed={drive['failed']} "
           f"slo_violations={slo_violations} "
-          f"padding_eff={payload['padding_efficiency']:.3f} "
+          f"padding_eff={payload['padding_efficiency'] or 0:.3f} "
           f"flushes={slot['flushes']}")
     if args.out:
         with open(args.out, "w") as fh:
@@ -183,7 +189,11 @@ def _run_serve(args, family) -> dict:
         "cli": "predict--serve", "model": args.model,
         "dataset": args.dataset, "rate_rps": args.rate,
         "p99_s": drive["p99_s"], "rejects": drive["rejects"],
-        "recompiles": recompiles})
+        "failed": drive["failed"], "recompiles": recompiles})
+    if drive["failed"]:
+        raise SystemExit(f"[serve] {drive['failed']} of "
+                         f"{args.serve_requests} responses failed; first "
+                         f"error: {drive['error']}")
     return payload
 
 
@@ -242,6 +252,7 @@ def main(argv=None):
     from repro.launch.common import add_obs_args, finish_obs, setup_obs
     add_obs_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     setup_obs(args)
 
     from repro.launch.common import DTYPES

@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from repro.utils.compile_cache import enable_compile_cache
 from repro.core import cdn_config, make_problem, scdn, tron, with_bundle_size
 from repro.core.scdn import SCDNConfig
 from repro.data.synthetic import train_accuracy
@@ -50,6 +51,7 @@ def main(argv=None):
     common.add_diag_args(ap)
     common.add_fault_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.sharded:
         args.backend = "sharded"
     if ((args.ckpt_dir or args.resume)

@@ -28,7 +28,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models import sharding as sh
 from repro.models.config import ModelConfig
-from repro.utils import compat
 
 Array = jax.Array
 
@@ -113,11 +112,11 @@ def apply_moe(cfg: ModelConfig, params, x: Array, mesh: Mesh,
     local = functools.partial(
         _moe_local, cfg=cfg, mesh=mesh, w_specs=w_specs, model_ax=model_ax)
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, w_specs["router"], w_specs["w_gate"],
                   w_specs["w_up"], w_specs["w_down"]),
-        out_specs=x_spec,
+        out_specs=x_spec, check_vma=False,
     )
     out = mapped(x, params["router"], params["w_gate"], params["w_up"],
                  params["w_down"])

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bundles as B
-from repro.core.pcdn import PCDNConfig, make_bundle_step
+from repro.core.pcdn import PCDNConfig, jit_over_problem, make_bundle_step
 from repro.core.problem import L1Problem
 from repro.engine import loop as engine_loop
 
@@ -52,9 +52,8 @@ def make_batch_outer(problem: L1Problem, cfg: PCDNConfig,
     Returns outer(w (B,n), z (B,s), key (B,2), c (B,)[, y (B,s)])
     -> (w, z, key, f, kkt, nnz), all B-leading.
     """
-    n = problem.n_features
-
-    def one(w, z, key, c, y):
+    def one(problem, w, z, key, c, y):
+        n = problem.n_features
         prob = problem.with_c(c)
         if y is not None:
             prob = prob.with_labels(y)
@@ -68,11 +67,12 @@ def make_batch_outer(problem: L1Problem, cfg: PCDNConfig,
         return w, z, key, f, kkt, nnz
 
     if batched_labels:
-        mapped = jax.vmap(one, in_axes=(0, 0, 0, 0, 0))
+        mapped = jax.vmap(one, in_axes=(None, 0, 0, 0, 0, 0))
     else:
-        mapped = jax.vmap(lambda w, z, key, c: one(w, z, key, c, None),
-                          in_axes=(0, 0, 0, 0))
-    return jax.jit(mapped)
+        mapped = jax.vmap(
+            lambda problem, w, z, key, c: one(problem, w, z, key, c, None),
+            in_axes=(None, 0, 0, 0, 0))
+    return jit_over_problem(mapped, problem)
 
 
 def solve_batch(problem: L1Problem, cfg: PCDNConfig,

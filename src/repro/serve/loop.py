@@ -700,7 +700,9 @@ def drive_poisson(loop: ServeLoop, X, rate_rps: float, n_requests: int,
     responses (overdue arrivals are submitted immediately and the
     generator lag reported — the open-loop property that distinguishes
     offered load from achieved throughput). Returns the results plus
-    latency quantiles at the MEASURED offered rate.
+    latency quantiles at the MEASURED offered rate; a request whose batch
+    failed, or that a quarantined slot refused, is counted under "failed"
+    (first error under "error") instead of cutting the run short.
     """
     if rate_rps <= 0:
         raise ValueError(f"rate_rps must be > 0, got {rate_rps}")
@@ -708,7 +710,7 @@ def drive_poisson(loop: ServeLoop, X, rate_rps: float, n_requests: int,
     arrive = np.cumsum(np.random.default_rng(seed).exponential(
         1.0 / rate_rps, size=n_requests))
     futures = []
-    rejects = 0
+    rejects, failed, first_error = 0, 0, None
     max_lag = 0.0
     t0 = time.perf_counter()
     for i in range(n_requests):
@@ -723,8 +725,19 @@ def drive_poisson(loop: ServeLoop, X, rate_rps: float, n_requests: int,
                                        budget_s=budget_s))
         except ServeOverload:
             rejects += 1
+        except SlotQuarantined as e:    # the slot stopped taking requests
+            failed += 1
+            first_error = first_error or e
     t_end = time.perf_counter()
-    results = [f.result(timeout=timeout_s) for f in futures]
+    results = []
+    for f in futures:
+        try:
+            results.append(f.result(timeout=timeout_s))
+        except TimeoutError:
+            raise
+        except Exception as e:          # the batch failed: count it
+            failed += 1
+            first_error = first_error or e
     lat = np.asarray([r.latency_s for r in results]) if results else \
         np.zeros((0,))
     return {
@@ -733,6 +746,8 @@ def drive_poisson(loop: ServeLoop, X, rate_rps: float, n_requests: int,
         "n_requests": n_requests,
         "responses": len(results),
         "rejects": rejects,
+        "failed": failed,
+        "error": None if first_error is None else repr(first_error),
         "generator_lag_s": max_lag,
         "p50_s": float(np.percentile(lat, 50)) if lat.size else None,
         "p99_s": float(np.percentile(lat, 99)) if lat.size else None,

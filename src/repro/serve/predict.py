@@ -209,8 +209,11 @@ def _dense_xla(X, union_idx, union_val, bias):
     """One shared active-union gather, then a small (B, U) x (U, K)
     contraction — the gather cost is paid once for all K models."""
     Xu = jnp.take(X, union_idx, axis=1)
-    # bf16 bank storage upcasts here: the contraction accumulates in f32
-    return Xu @ union_val.T.astype(jnp.float32) + bias[None, :]
+    # bf16 bank storage upcasts here: the contraction accumulates in f32,
+    # at f32 accuracy (a TPU's default precision rounds the operands to
+    # bf16, which put served margins ~3e-3 off float64 scoring)
+    return jnp.dot(Xu, union_val.T.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST) + bias[None, :]
 
 
 @jax.jit
@@ -218,7 +221,8 @@ def _matmul_xla(X, W, bias):
     """The densified baseline scorer: z = X @ W.T. Beats the union
     gather at low weight sparsity / small batch (the measured crossover
     table of BENCH_serve.json; route='auto' picks per call)."""
-    return X @ W.T + bias[None, :]
+    return jnp.dot(X, W.T, precision=jax.lax.Precision.HIGHEST) + \
+        bias[None, :]
 
 
 # -- dense-layout route selection (the measured crossover) -------------------
@@ -297,12 +301,14 @@ def pick_route(sparsity: float, batch: int) -> str:
 
 
 def scorer_cache_sizes() -> dict:
-    """Compiled-program counts of the jitted scorers + install program —
-    the hot-swap regression tests pin these flat across traffic and
-    swaps (a growing cache is a recompile)."""
+    """Compiled-program counts of the jitted scorers (XLA and Pallas
+    routes) + install program — the hot-swap regression tests pin these
+    flat across traffic and swaps (a growing cache is a recompile)."""
     sizes = {"dense_xla": _dense_xla._cache_size(),
              "csc_xla": _csc_xla._cache_size(),
-             "matmul_xla": _matmul_xla._cache_size()}
+             "matmul_xla": _matmul_xla._cache_size(),
+             "dense_kernel": ops._margins_dense_pallas._cache_size(),
+             "csc_kernel": ops._margins_csc_pallas._cache_size()}
     from repro.serve import loop as _loop   # lazy: loop imports this module
     sizes["install"] = _loop._install._cache_size()
     return sizes

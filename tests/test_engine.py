@@ -19,6 +19,7 @@ from repro.core import PCDNConfig, make_problem, solve
 from repro.data import make_classification
 from repro.engine import (LocalBackend, ShardedBackend, ShardedPCDNConfig,
                           loop as engine_loop)
+from repro.launch.mesh import make_host_mesh, make_mesh
 
 
 @pytest.fixture(scope="module")
@@ -30,7 +31,10 @@ def test_engine_solve_matches_pcdn_solve(data):
     """pcdn.solve is a thin engine caller — same result through either."""
     X, y, _ = data
     prob = make_problem(X, y, c=1.0)
-    cfg = PCDNConfig(P=32, max_outer=80, tol_kkt=1e-4)
+    # tol 1e-3, as the other tests of this fixture use: in f32 this
+    # problem's KKT plateaus near 1.2e-4, so a 1e-4 stop is never reached
+    # and neither route would report convergence
+    cfg = PCDNConfig(P=32, max_outer=80, tol_kkt=1e-3)
     direct = solve(prob, cfg)
     via_engine = engine_loop.solve(
         LocalBackend(prob, cfg), prob.c, max_outer=cfg.max_outer,
@@ -39,6 +43,19 @@ def test_engine_solve_matches_pcdn_solve(data):
     assert via_engine.objective == pytest.approx(direct.objective)
     np.testing.assert_array_equal(np.asarray(direct.w),
                                   np.asarray(via_engine.w))
+
+
+def test_make_host_mesh_raises_on_a_mesh_it_cannot_build():
+    """A mesh larger than the visible devices is an error, never a
+    quietly smaller mesh; the mesh it builds has Auto axes."""
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match=f"needs {n + 1} devices"):
+        make_host_mesh(n + 1, 1)
+    with pytest.raises(ValueError, match="needs"):
+        make_host_mesh(1, 0)
+    mesh = make_host_mesh(1, 1)
+    assert mesh.shape == {"data": 1, "model": 1}
+    assert set(mesh.axis_types) == {jax.sharding.AxisType.Auto}
 
 
 def test_local_backend_contract(data):
@@ -61,7 +78,7 @@ def test_sharded_backend_1x1_mesh_matches_local(data):
     """The backend contract holds on a trivial mesh without any forced
     device count — same engine loop, same answer as the local backend."""
     X, y, _ = data
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # tol 1e-3, like test_sharded_pcdn: at 1e-4 the sharded line search's
     # different f32 reduction order gives a long pre-existing KKT plateau
     cfg = ShardedPCDNConfig(P_local=32, c=1.0, tol_kkt=1e-3)
@@ -116,9 +133,10 @@ from repro.data import make_classification
 from repro.engine import (LocalBackend, ShardedBackend, ShardedPCDNConfig,
                           loop as engine_loop)
 from repro.path import PathConfig, run_path
+from repro.launch.mesh import make_mesh
 
 X, y, _ = make_classification(512, 256, sparsity=0.7, corr=0.4, seed=3)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 tol = 1e-4
 
 # 1) full solve trajectory WITH SHRINKING: local vs sharded to fp32

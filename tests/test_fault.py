@@ -21,6 +21,7 @@ from repro.engine import (LocalBackend, ShardedBackend, ShardedPCDNConfig,
                           loop as engine_loop)
 from repro import fault
 from repro.fault import atomic
+from repro.launch.mesh import make_mesh
 from repro.path.driver import PathConfig, run_path
 
 # tol reachable at EVERY bundle size the backoff schedule can visit:
@@ -162,7 +163,7 @@ def test_nan_guard_local(prob):
 
 def test_nan_guard_sharded_1x1(data):
     X, y, _ = data
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     backend = ShardedBackend(X, y, mesh,
                              ShardedPCDNConfig(P_local=32, c=1.0,
                                                tol_kkt=TOL))
@@ -347,13 +348,14 @@ from repro.engine import (LocalBackend, ShardedBackend, ShardedPCDNConfig,
                           loop as engine_loop)
 from repro.core import PCDNConfig, make_problem
 from repro.fault import SolveCheckpointer, host_state
+from repro.launch.mesh import make_mesh
 
 X, y, _ = make_classification(256, 64, sparsity=0.8, corr=0.3, seed=5)
 assert len(jax.devices()) == 8
 
 # writer: a 2x1 mesh runs 6 iterations and checkpoints every 3rd
 cfg = ShardedPCDNConfig(P_local=16, c=1.0, tol_kkt=1e-3)
-wb = ShardedBackend(X, y, jax.make_mesh((2, 1), ("data", "model")), cfg)
+wb = ShardedBackend(X, y, make_mesh((2, 1), ("data", "model")), cfg)
 ck = SolveCheckpointer("CKDIR", every=3)
 st, res = engine_loop.run_outer_loop(
     wb.outer, wb.init_state(), 1.0, max_outer=6, tol_kkt=0.0,
@@ -361,7 +363,7 @@ st, res = engine_loop.run_outer_loop(
 snap5 = ck.manager.load_raw(5)     # the host image of iteration 5
 
 # reader 1: a DIFFERENT device count (4x2 mesh) restores the snapshot
-rb = ShardedBackend(X, y, jax.make_mesh((4, 2), ("data", "model")), cfg)
+rb = ShardedBackend(X, y, make_mesh((4, 2), ("data", "model")), cfg)
 st4, meta = SolveCheckpointer("CKDIR", every=3).restore_solve(rb)
 assert meta["outer_iter"] == 5
 got = host_state(rb, st4)

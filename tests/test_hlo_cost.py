@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.utils import compat, hlo_cost
+from repro.utils import hlo_cost
 from repro.utils.hlo import Roofline
 
 
@@ -22,9 +22,8 @@ def test_scan_trip_count_multiplied():
     x = jax.ShapeDtypeStruct((128, 256), jnp.float32)
     w = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     compiled = _compile(f, x, w)
-    # XLA's own analysis counts the loop body once (the bug we fix);
-    # compat.cost_analysis flattens the jax-0.4.x list-of-dicts return.
-    assert compat.cost_analysis(compiled)["flops"] < 2 * 2 * 128 * 256 * 256
+    # XLA's own analysis counts the loop body once (the bug we fix)
+    assert compiled.cost_analysis()["flops"] < 2 * 2 * 128 * 256 * 256
     mc = hlo_cost.analyze(compiled.as_text())
     assert abs(mc.flops - 8 * 2 * 128 * 256 * 256) / mc.flops < 1e-6
     assert 8 in mc.trip_counts.values()

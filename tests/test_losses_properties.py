@@ -7,7 +7,7 @@ Autodiff targets the PLAIN textbook forms (paper Eq. 2/3), not the
 log1p/maximum-stabilized implementations: grad-of-stable-form has
 spurious subgradient artifacts exactly at margin 0 (jnp.maximum /
 jnp.abs tie-breaking) where the true losses are perfectly smooth.
-Runs under `jax.experimental.enable_x64` (scoped, not global): in f32
+Runs under `jax.enable_x64` (scoped, not global): in f32
 the two only agree to ~eps at saturated margins, forcing vacuous
 tolerances.
 """
@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
-from jax.experimental import enable_x64
 
 from repro.core.losses import HESSIAN_FLOOR, get_loss
 from repro.core.problem import make_problem
@@ -37,7 +36,7 @@ def _plain_value(name):
 
 def _check_scalar(name, z, y, rel=1e-5, abs_=1e-12):
     """dz/d2z at a scalar margin vs jax.grad of the plain form, in f64."""
-    with enable_x64():
+    with jax.enable_x64(True):
         loss = get_loss(name)
         plain = _plain_value(name)
         f = lambda zz: plain(zz, jnp.float64(y))
@@ -84,7 +83,7 @@ def test_vector_forms_match_hessian_diagonal(zs, ys, name):
     the summed loss — the exact contract problem.grad/hess_factor uses;
     the off-diagonal curvature is zero by per-sample separability."""
     k = min(len(zs), len(ys))
-    with enable_x64():
+    with jax.enable_x64(True):
         z = jnp.asarray(zs[:k], jnp.float64)
         y = jnp.asarray(ys[:k], jnp.float64)
         if name == "squared_hinge":

@@ -14,6 +14,7 @@ from repro.models import decode as dec
 from repro.models.transformer import Model
 from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.train.steps import make_train_step
+from repro.launch.mesh import make_mesh
 
 MESH = None
 
@@ -21,7 +22,7 @@ MESH = None
 def mesh():
     global MESH
     if MESH is None:
-        MESH = jax.make_mesh((1, 1), ("data", "model"))
+        MESH = make_mesh((1, 1), ("data", "model"))
     return MESH
 
 

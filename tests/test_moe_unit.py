@@ -8,11 +8,12 @@ from repro.configs import get_config
 from repro.models import moe as moe_mod
 from repro.models import sharding as sh
 from repro.models.transformer import Model
+from repro.launch.mesh import make_mesh
 
 
 def setup(arch="deepseek-moe-16b"):
     cfg = get_config(arch, reduced=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = Model(cfg, mesh)
     key = jax.random.PRNGKey(0)
     params = sh.init_params(key, moe_mod.moe_decls(cfg))

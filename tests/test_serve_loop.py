@@ -486,3 +486,34 @@ def test_quarantine_disabled_and_param_validation(monkeypatch):
         ServeLoop(fam, buckets=(1,), batch_retries=-1)
     with pytest.raises(ValueError, match="quarantine_after"):
         ServeLoop(fam, buckets=(1,), quarantine_after=0)
+
+
+def test_predict_serve_exits_nonzero_when_a_batch_fails(monkeypatch,
+                                                        tmp_path):
+    """`launch.predict --serve` counts failed responses and exits non-zero
+    when there are any, instead of reporting a run that served nothing."""
+    import repro.serve.loop as loop_mod
+    from repro.data.libsvm import save_libsvm
+    from repro.launch import predict as launch_predict
+    n = 32
+    model = tmp_path / "m.json"
+    art.save_model(str(model), _binary_family(n, 5, seed=8))
+    X = RNG.standard_normal((16, n)).astype(np.float32)
+    reqs = tmp_path / "r.svm"
+    save_libsvm(str(reqs), X, np.where(X[:, 0] > 0, 1.0, -1.0))
+
+    real = margins_dense
+
+    def broken(bank, X, **kw):
+        # warmup (main thread) compiles; traffic (scheduler thread) fails
+        if threading.current_thread() is threading.main_thread():
+            return real(bank, X, **kw)
+        raise RuntimeError("scorer lost its device")
+
+    monkeypatch.setattr(loop_mod, "margins_dense", broken)
+    with pytest.raises(SystemExit) as exc:
+        launch_predict.main(["--model", str(model), "--dataset", str(reqs),
+                             "--serve", "--serve-requests", "12",
+                             "--rate", "400", "--max-batch", "4"])
+    assert "12 of 12 responses failed" in str(exc.value)
+    assert "scorer lost its device" in str(exc.value)

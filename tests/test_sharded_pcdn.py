@@ -16,10 +16,11 @@ import jax, numpy as np
 from repro.core.sharded import ShardedPCDNConfig, solve_sharded
 from repro.core import make_problem, PCDNConfig, solve
 from repro.data import make_classification
+from repro.launch.mesh import make_mesh
 
 X, y, _ = make_classification(512, 256, sparsity=0.7, corr=0.4, seed=3)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = ShardedPCDNConfig(P_local=16, c=1.0, data_axes=("data",))
 w, f, conv, k, hist = solve_sharded(X, y, mesh, cfg, max_outer=40)
 assert conv, "sharded PCDN must converge"
@@ -39,7 +40,7 @@ assert abs(fs - res.objective) / abs(res.objective) < 1e-4, (fs,
                                                             res.objective)
 
 # multi-pod (3-axis) mesh
-mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg3 = ShardedPCDNConfig(P_local=32, c=1.0, data_axes=("pod", "data"))
 w3, f3, conv3, k3, _ = solve_sharded(X, y, mesh3, cfg3, max_outer=40)
 assert conv3
@@ -69,8 +70,9 @@ import jax
 from repro.configs import get_config
 from repro.models.transformer import Model
 from repro.launch.specs import train_batch_specs
+from repro.launch.mesh import make_mesh
 
-mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+mesh1 = make_mesh((1, 1), ("data", "model"))
 for arch, shape in [("deepseek-moe-16b", (2, 4)), ("grok-1-314b", (1, 8)),
                     ("grok-1-314b", (2, 2))]:
     cfg = get_config(arch, reduced=True)
@@ -78,7 +80,7 @@ for arch, shape in [("deepseek-moe-16b", (2, 4)), ("grok-1-314b", (1, 8)),
     params = m1.init_params(jax.random.PRNGKey(0))
     batch = train_batch_specs(cfg, batch=4, seq=16, concrete=True, seed=2)
     ref = float(m1.loss_fn(params, batch))
-    meshN = jax.make_mesh(shape, ("data", "model"))
+    meshN = make_mesh(shape, ("data", "model"))
     mN = Model(cfg, meshN)
     pN = mN.shard_params(params)
     lossN = float(jax.jit(mN.loss_fn)(pN, batch))
@@ -102,8 +104,9 @@ import jax
 from repro.configs import get_config
 from repro.models.transformer import Model
 from repro.launch.specs import train_batch_specs
+from repro.launch.mesh import make_mesh
 
-mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+mesh1 = make_mesh((1, 1), ("data", "model"))
 for arch in ["yi-6b", "recurrentgemma-2b", "falcon-mamba-7b",
              "whisper-small"]:
     cfg = get_config(arch, reduced=True)
@@ -111,7 +114,7 @@ for arch in ["yi-6b", "recurrentgemma-2b", "falcon-mamba-7b",
     params = m1.init_params(jax.random.PRNGKey(0))
     batch = train_batch_specs(cfg, batch=4, seq=16, concrete=True, seed=2)
     ref = float(m1.loss_fn(params, batch))
-    meshN = jax.make_mesh((2, 4), ("data", "model"))
+    meshN = make_mesh((2, 4), ("data", "model"))
     mN = Model(cfg, meshN)
     pN = mN.shard_params(params)
     lossN = float(jax.jit(mN.loss_fn)(pN, batch))

@@ -220,3 +220,26 @@ def test_gather_slab_types():
     slab = ps.design.gather_slab(jnp.arange(4, dtype=jnp.int32))
     assert isinstance(slab, SparseSlab)
     assert slab.rows.shape == (4, ps.design.k_max)
+
+
+def test_padded_csc_to_csr_sums_shared_slots_and_roundtrips_libsvm(tmp_path):
+    """The generator's padded-CSC becomes CSR without densifying: slots
+    that share a (row, column) pair add up (what X @ w gives them), and
+    the LIBSVM text written from the CSR loads back as the same matrix."""
+    from repro.data.libsvm import (load_libsvm, padded_csc_to_csr,
+                                   save_libsvm)
+    from repro.data.synthetic import make_sparse_classification
+    Xp, y, _ = make_sparse_classification(40, 30, nnz_per_col=12, seed=4)
+    s, n = Xp.shape
+    mask = Xp.col_rows < s
+    dense = np.zeros((s, n))
+    np.add.at(dense, (Xp.col_rows[mask],
+                      np.nonzero(mask)[0]), Xp.col_vals[mask])
+    csr = padded_csc_to_csr(Xp)
+    assert csr.nnz == np.count_nonzero(dense)
+    np.testing.assert_allclose(csr.to_dense(), dense, rtol=1e-6, atol=1e-7)
+    path = str(tmp_path / "x.svm")
+    save_libsvm(path, csr, y)
+    X2, y2 = load_libsvm(path, n_features=n, layout="csr")
+    np.testing.assert_allclose(X2.to_dense(), dense, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(y2, y)
