@@ -155,6 +155,12 @@ def make_bundle_step(problem: L1Problem, cfg: PCDNConfig):
       O(P * k_max * Q) and solve time stops scaling with s. With
       use_kernels the whole support step is ONE fused Pallas launch
       (kernels/pcdn_bundle).
+
+    Both shapes name their phases with `jax.named_scope`, which only
+    changes op metadata (the device trace attributes each operation to
+    its phase): `pcdn.direction` (slab and w_B gathers, row support, g,
+    h, d, Delta), `pcdn.linesearch` (X_B d and the Armijo search) and
+    `pcdn.update` (the w and z updates).
     """
     loss = problem.loss
     gamma = cfg.armijo.gamma
@@ -169,42 +175,53 @@ def make_bundle_step(problem: L1Problem, cfg: PCDNConfig):
 
         def step(carry, idx):
             w, z = carry
-            slab = design.gather_slab(idx)
-            w_B, _ = B.gather_vec(w, idx)
-            support, pos = design.slab_row_support(slab)
-            z_R = jnp.take(z, support, mode="fill", fill_value=0)
-            y_R = jnp.take(problem.y, support, mode="fill", fill_value=1)
+            with jax.named_scope("pcdn.direction"):
+                slab = design.gather_slab(idx)
+                w_B, _ = B.gather_vec(w, idx)
+                support, pos = design.slab_row_support(slab)
+                z_R = jnp.take(z, support, mode="fill", fill_value=0)
+                y_R = jnp.take(problem.y, support, mode="fill",
+                               fill_value=1)
             if fuse:
-                upd_w, upd_z, alpha, n_steps = kops.pcdn_bundle(
-                    slab.vals, pos, z_R, y_R, w_B,
-                    candidate_alphas(cfg.armijo, z.dtype), problem.c,
-                    kind=problem.loss_name, l2=problem.elastic_net_l2,
-                    sigma=cfg.armijo.sigma, gamma=gamma)
-                w = B.scatter_add(w, idx, upd_w)
-                z = design.scatter_support(z, support, upd_z)
+                # the fused kernel computes direction, line search and
+                # the support-local updates in ONE launch: it is named
+                # as the line search, the phase that dominates it
+                with jax.named_scope("pcdn.linesearch"):
+                    upd_w, upd_z, alpha, n_steps = kops.pcdn_bundle(
+                        slab.vals, pos, z_R, y_R, w_B,
+                        candidate_alphas(cfg.armijo, z.dtype), problem.c,
+                        kind=problem.loss_name, l2=problem.elastic_net_l2,
+                        sigma=cfg.armijo.sigma, gamma=gamma)
+                with jax.named_scope("pcdn.update"):
+                    w = B.scatter_add(w, idx, upd_w)
+                    z = design.scatter_support(z, support, upd_z)
                 return (w, z), (n_steps, alpha)
-            if cfg.use_kernels:
-                # backtracking search: no fused step, but the direction
-                # still routes through the sparse kernel — pos is the
-                # support-local row array, u/v handed over in support
-                # order (same composition as the sharded backend)
-                u_R = problem.grad_factor_at(z_R, y_R)
-                v_R = problem.hess_factor_at(z_R, y_R)
-                d, g, h = kops.pcdn_sparse_direction(
-                    pos, slab.vals, u_R, v_R, w_B,
-                    l2=problem.elastic_net_l2)
-            else:
-                g, h = problem.bundle_grad_hess_support(slab, pos, z_R,
-                                                        y_R, w_B)
-                d = newton_direction(g, h, w_B)
-            Delta = delta_decrement(g, h, w_B, d, gamma)
-            delta_R = design.slab_matvec_support(slab, pos, d)
-            ls_fn = (armijo_support if cfg.ls_kind == "batched"
-                     else armijo_backtracking)
-            res = ls_fn(loss, problem.c, z_R, delta_R, y_R, w_B, d, Delta,
-                        cfg.armijo, l2=problem.elastic_net_l2)
-            w = B.scatter_add(w, idx, res.alpha * d)
-            z = design.scatter_support(z, support, res.alpha * delta_R)
+            with jax.named_scope("pcdn.direction"):
+                if cfg.use_kernels:
+                    # backtracking search: no fused step, but the
+                    # direction still routes through the sparse kernel —
+                    # pos is the support-local row array, u/v handed
+                    # over in support order (same composition as the
+                    # sharded backend)
+                    u_R = problem.grad_factor_at(z_R, y_R)
+                    v_R = problem.hess_factor_at(z_R, y_R)
+                    d, g, h = kops.pcdn_sparse_direction(
+                        pos, slab.vals, u_R, v_R, w_B,
+                        l2=problem.elastic_net_l2)
+                else:
+                    g, h = problem.bundle_grad_hess_support(slab, pos, z_R,
+                                                            y_R, w_B)
+                    d = newton_direction(g, h, w_B)
+                Delta = delta_decrement(g, h, w_B, d, gamma)
+            with jax.named_scope("pcdn.linesearch"):
+                delta_R = design.slab_matvec_support(slab, pos, d)
+                ls_fn = (armijo_support if cfg.ls_kind == "batched"
+                         else armijo_backtracking)
+                res = ls_fn(loss, problem.c, z_R, delta_R, y_R, w_B, d,
+                            Delta, cfg.armijo, l2=problem.elastic_net_l2)
+            with jax.named_scope("pcdn.update"):
+                w = B.scatter_add(w, idx, res.alpha * d)
+                z = design.scatter_support(z, support, res.alpha * delta_R)
             return (w, z), (res.n_steps, res.alpha)
 
         return step
@@ -213,27 +230,30 @@ def make_bundle_step(problem: L1Problem, cfg: PCDNConfig):
 
     def step(carry, idx):
         w, z = carry
-        slab = problem.design.gather_slab(idx)
-        w_B, _ = B.gather_vec(w, idx)
-        if cfg.use_kernels:
-            u = problem.grad_factor(z)
-            v = problem.hess_factor(z)
-            if isinstance(slab, SparseSlab):
-                d, g, h = kops.pcdn_sparse_direction(
-                    slab.rows, slab.vals, u, v, w_B,
-                    l2=problem.elastic_net_l2)
+        with jax.named_scope("pcdn.direction"):
+            slab = problem.design.gather_slab(idx)
+            w_B, _ = B.gather_vec(w, idx)
+            if cfg.use_kernels:
+                u = problem.grad_factor(z)
+                v = problem.hess_factor(z)
+                if isinstance(slab, SparseSlab):
+                    d, g, h = kops.pcdn_sparse_direction(
+                        slab.rows, slab.vals, u, v, w_B,
+                        l2=problem.elastic_net_l2)
+                else:
+                    d, g, h = kops.pcdn_direction(
+                        slab.XB, u, v, w_B, l2=problem.elastic_net_l2)
             else:
-                d, g, h = kops.pcdn_direction(
-                    slab.XB, u, v, w_B, l2=problem.elastic_net_l2)
-        else:
-            g, h = problem.bundle_grad_hess(z, slab, w_B)
-            d = newton_direction(g, h, w_B)
-        Delta = delta_decrement(g, h, w_B, d, gamma)
-        delta_z = problem.design.slab_matvec(slab, d)
-        res = ls(loss, problem.c, z, delta_z, problem.y, w_B, d, Delta,
-                 cfg.armijo, l2=problem.elastic_net_l2)
-        w = B.scatter_add(w, idx, res.alpha * d)
-        z = z + res.alpha * delta_z
+                g, h = problem.bundle_grad_hess(z, slab, w_B)
+                d = newton_direction(g, h, w_B)
+            Delta = delta_decrement(g, h, w_B, d, gamma)
+        with jax.named_scope("pcdn.linesearch"):
+            delta_z = problem.design.slab_matvec(slab, d)
+            res = ls(loss, problem.c, z, delta_z, problem.y, w_B, d, Delta,
+                     cfg.armijo, l2=problem.elastic_net_l2)
+        with jax.named_scope("pcdn.update"):
+            w = B.scatter_add(w, idx, res.alpha * d)
+            z = z + res.alpha * delta_z
         return (w, z), (res.n_steps, res.alpha)
 
     return step
@@ -349,16 +369,18 @@ def make_path_outer(problem: L1Problem, cfg: PCDNConfig):
                 qs = steps.astype(jnp.int32)
                 alphas = alphas.astype(w.dtype)
 
-        f = prob.objective_from_margins(z, w)
-        g = prob.full_grad(z, w)
-        viol = prob.kkt_violation_from_grad(w, g)
-        kkt = jnp.max(viol)
-        if cfg.shrink:
-            interior = (w == 0) & (jnp.abs(g) < 1.0 - cfg.shrink_tol)
-            active = active & ~interior
-            active = active | (recheck & (viol > cfg.tol_kkt))
-        nnz = jnp.sum(w != 0)
-        n_active = jnp.sum(active.astype(jnp.int32))
+        # objective, full gradient, KKT violation and the shrink update
+        with jax.named_scope("pcdn.kkt"):
+            f = prob.objective_from_margins(z, w)
+            g = prob.full_grad(z, w)
+            viol = prob.kkt_violation_from_grad(w, g)
+            kkt = jnp.max(viol)
+            if cfg.shrink:
+                interior = (w == 0) & (jnp.abs(g) < 1.0 - cfg.shrink_tol)
+                active = active & ~interior
+                active = active | (recheck & (viol > cfg.tol_kkt))
+            nnz = jnp.sum(w != 0)
+            n_active = jnp.sum(active.astype(jnp.int32))
         base = (w, z, key, f, kkt, nnz, mean_q, active, n_active)
         if cfg.record_aux:
             base = base + ((qs, alphas),)

@@ -1,7 +1,7 @@
 """Markdown solver-health report (DESIGN.md section 15.4).
 
     python -m repro.diag.report --report report.json \
-        [--metrics run.jsonl] [--trace trace.json] \
+        [--metrics run.jsonl] [--trace TRACE_DIR] \
         [--dataset NAME|FILE --layout auto] [-o health.md]
 
 Assembles every diagnostics surface into one markdown document:
@@ -26,6 +26,8 @@ saved artifacts after the fact.
 from __future__ import annotations
 
 import argparse
+import glob
+import gzip
 import json
 import os
 import sys
@@ -361,6 +363,21 @@ def _load_design(dataset: str, layout: str, seed: int):
     return as_design(X, layout=layout)
 
 
+def _read_trace(path: str) -> dict:
+    """The Chrome-trace JSON that `--trace-out DIR` leaves under DIR
+    (`plugins/profile/<run>/perfetto_trace.json.gz`), or a given file."""
+    if os.path.isdir(path):
+        found = sorted(glob.glob(os.path.join(
+            path, "**", "perfetto_trace.json.gz"), recursive=True),
+            key=os.path.getmtime)
+        if not found:
+            raise SystemExit(f"no perfetto_trace.json.gz under {path}")
+        path = found[-1]
+    opener = gzip.open if path.endswith(".gz") else open
+    with opener(path, "rt") as fh:
+        return json.load(fh)
+
+
 def _read_jsonl(path: str) -> list:
     records = []
     with open(path) as fh:
@@ -382,8 +399,9 @@ def main(argv=None) -> int:
     ap.add_argument("--metrics", default=None, metavar="JSONL",
                     help="metrics run-record log (--metrics-out); the "
                          "last record is summarized")
-    ap.add_argument("--trace", default=None, metavar="JSON",
-                    help="Chrome-trace file (--trace-out)")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="a --trace-out directory (its "
+                         "perfetto_trace.json.gz), or that file")
     ap.add_argument("--dataset", default=None,
                     help="recompute the certified-P table from this "
                          "dataset (profile name or libsvm file)")
@@ -409,10 +427,7 @@ def main(argv=None) -> int:
         with open(args.report) as fh:
             report = json.load(fh)
     metrics_records = _read_jsonl(args.metrics) if args.metrics else None
-    trace = None
-    if args.trace:
-        with open(args.trace) as fh:
-            trace = json.load(fh)
+    trace = _read_trace(args.trace) if args.trace else None
     safep_record = None
     if args.dataset:
         design = _load_design(args.dataset, args.layout, args.seed)

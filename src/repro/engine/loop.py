@@ -145,10 +145,9 @@ def _build_postmortem(hist: dict, aux_q: list, aux_alpha: list,
         ls_steps=np.asarray(hist["ls_steps"]),
         bundle_q=np.asarray(aux_q) if aux_q else None,
         bundle_alpha=np.asarray(aux_alpha) if aux_alpha else None)
-    obs.instant("engine.divergence_postmortem", "engine",
-                args={"k": k,
-                      "objective_growth": postmortem["objective_growth"],
-                      "deepest_mean_q": postmortem["deepest_mean_q"]})
+    obs.instant("engine.divergence_postmortem",
+                {"k": k, "objective_growth": postmortem["objective_growth"],
+                 "deepest_mean_q": postmortem["deepest_mean_q"]})
     return postmortem
 
 
@@ -224,112 +223,128 @@ def run_outer_loop(outer: Callable, state: EngineState, c: float, *,
     aux_alpha: list = []
     kkt_rows: list = []
     t0 = time.perf_counter()
+    t_last = 0.0
     converged = diverged = nonfinite = False
     postmortem = None
     f = f_good = float("nan")
     prev_active = None
     k = start_iter - 1
     for k in range(start_iter, max_outer):
-        # iteration 0 always rechecks so a stale warm-started active set
-        # (e.g. carried across path points) is repaired immediately.
-        recheck = jnp.asarray(k == 0 or recheck_every <= 1
-                              or k % recheck_every == 0)
-        t_iter = time.perf_counter_ns()
-        # the pre-iteration carry is the rollback target should this
-        # iteration come back non-finite
-        prev_state = (w, z, key, active)
-        out = outer(w, z, key, active, recheck, c_arr)
-        w, z, key, f_, kkt, nnz, mean_q, active, n_active = out[:9]
-        aux = kkt_vec = None
-        for extra in out[9:]:
-            if isinstance(extra, tuple):
-                aux = extra
-            else:
-                kkt_vec = extra
-        # sync BEFORE timestamping: float(f_) below only blocks on f_,
-        # and a backend dispatching asynchronously would otherwise get
-        # this iteration's device time attributed to a later row
-        # (tests/test_obs.py pins monotone per-iteration times that sum
-        # to ~ the loop total).
-        jax.block_until_ready((w, z, active))
-        t_now = time.perf_counter_ns()
-        f = float(f_)
-        kkt_f = float(kkt)
-        n_active_i = int(n_active)
-        hist["outer_iter"].append(k)
-        hist["objective"].append(f)
-        hist["kkt"].append(kkt_f)
-        hist["nnz"].append(int(nnz))
-        hist["ls_steps"].append(float(mean_q))
-        hist["wall_time"].append(time.perf_counter() - t0)
-        hist["n_active"].append(n_active_i)
-        if aux is not None:
-            q_np = np.asarray(aux[0])
-            a_np = np.asarray(aux[1])
-            aux_q.append(q_np)
-            aux_alpha.append(a_np)
-            if obs.metrics_enabled():
-                ran = q_np >= 0          # sentinel -1: bundle did not run
-                obs.observe_many("solver.bundle_q", q_np[ran],
-                                 bounds=obs.Q_BOUNDS)
-                obs.observe_many("solver.bundle_alpha", a_np[ran],
-                                 bounds=obs.ALPHA_BOUNDS)
-        if kkt_vec is not None:
-            kkt_rows.append(np.asarray(kkt_vec))
-        if obs.metrics_enabled():
-            obs.inc("solver.outer_iters")
-            obs.observe("solver.iter_seconds", (t_now - t_iter) / 1e9)
-            obs.observe("solver.mean_q", float(mean_q), bounds=obs.Q_BOUNDS)
-            obs.set_gauge("solver.n_active", n_active_i)
-            obs.set_gauge("solver.kkt", kkt_f)
-            if prev_active is not None and n_active_i != prev_active:
-                if n_active_i < prev_active:
-                    obs.inc("solver.shrink_events",
-                            prev_active - n_active_i)
+        # the iteration's phases are profiler spans (one check each when
+        # no profiler records): dispatch, the wait for the device, the
+        # scalar pulls, and the host bookkeeping between iterations
+        with obs.span("engine.outer") as it_span:
+            with obs.span("engine.dispatch"):
+                # iteration 0 always rechecks so a stale warm-started
+                # active set (e.g. carried across path points) is
+                # repaired immediately.
+                recheck = jnp.asarray(k == 0 or recheck_every <= 1
+                                      or k % recheck_every == 0)
+                # the pre-iteration carry is the rollback target should
+                # this iteration come back non-finite
+                prev_state = (w, z, key, active)
+                out = outer(w, z, key, active, recheck, c_arr)
+            w, z, key, f_, kkt, nnz, mean_q, active, n_active = out[:9]
+            aux = kkt_vec = None
+            for extra in out[9:]:
+                if isinstance(extra, tuple):
+                    aux = extra
                 else:
-                    obs.inc("solver.unshrink_events",
-                            n_active_i - prev_active)
-        prev_active = n_active_i
-        obs.complete("engine.outer", "engine", t_iter, t_now,
-                     args={"k": k, "objective": f, "kkt": kkt_f,
-                           "mean_q": float(mean_q),
-                           "n_active": n_active_i})
-        if callback is not None:
-            callback(k, w, f, kkt_f, float(mean_q))
-        # non-finite detector (DESIGN.md section 16.3): always on, free
-        # on the hot path (f/kkt are already host floats here)
-        finite = np.isfinite(f) and np.isfinite(kkt_f)
-        if finite and check_finite_w:
-            finite = bool(jnp.all(jnp.isfinite(w)))
-        if not finite:
-            diverged = nonfinite = True
-            obs.inc("solver.nonfinite_trips")
-            obs.instant("engine.nonfinite_guard", "engine",
-                        args={"k": k, "objective": f, "kkt": kkt_f})
-            postmortem = _build_postmortem(hist, aux_q, aux_alpha, k)
-            # roll the carry back to the last good iterate: the poisoned
-            # state must never leak into warm starts, checkpoints or the
-            # returned weights
-            w, z, key, active = prev_state
-            f = f_good
-            break
-        f_good = f
-        if state_callback is not None:
-            state_callback(k, EngineState(w, z, key, active), f, kkt_f)
-        if divergence_guard is not None and divergence_guard(f):
-            diverged = True
-            obs.inc("solver.divergence_trips")
-            obs.instant("engine.divergence_guard", "engine",
-                        args={"k": k, "objective": f})
-            postmortem = _build_postmortem(hist, aux_q, aux_alpha, k)
-            break
-        if kkt_f <= tol_kkt:
-            converged = True
-            break
-        if f_star is not None and tol_rel_obj > 0:
-            if (f - f_star) <= tol_rel_obj * abs(f_star):
-                converged = True
-                break
+                    kkt_vec = extra
+            # sync BEFORE timestamping: float(f_) below only blocks on f_,
+            # and a backend dispatching asynchronously would otherwise get
+            # this iteration's device time attributed to a later row
+            # (tests/test_obs.py pins monotone per-iteration times that
+            # sum to ~ the loop total).
+            with obs.span("engine.wait"):
+                jax.block_until_ready((w, z, active))
+            t_now = time.perf_counter() - t0
+            with obs.span("engine.pull"):
+                f = float(f_)
+                kkt_f = float(kkt)
+                n_active_i = int(n_active)
+                nnz_i = int(nnz)
+                mean_q_f = float(mean_q)
+                if aux is not None:
+                    q_np = np.asarray(aux[0])
+                    a_np = np.asarray(aux[1])
+                if kkt_vec is not None:
+                    kkt_rows.append(np.asarray(kkt_vec))
+            if it_span is not None:
+                it_span.set_metadata(k=k, objective=f, kkt=kkt_f,
+                                     mean_q=mean_q_f, n_active=n_active_i)
+            with obs.span("engine.record"):
+                hist["outer_iter"].append(k)
+                hist["objective"].append(f)
+                hist["kkt"].append(kkt_f)
+                hist["nnz"].append(nnz_i)
+                hist["ls_steps"].append(mean_q_f)
+                hist["wall_time"].append(t_now)
+                hist["n_active"].append(n_active_i)
+                if aux is not None:
+                    aux_q.append(q_np)
+                    aux_alpha.append(a_np)
+                    if obs.metrics_enabled():
+                        ran = q_np >= 0   # sentinel -1: bundle did not run
+                        obs.observe_many("solver.bundle_q", q_np[ran],
+                                         bounds=obs.Q_BOUNDS)
+                        obs.observe_many("solver.bundle_alpha", a_np[ran],
+                                         bounds=obs.ALPHA_BOUNDS)
+                if obs.metrics_enabled():
+                    obs.inc("solver.outer_iters")
+                    obs.observe("solver.iter_seconds", t_now - t_last)
+                    obs.observe("solver.mean_q", mean_q_f,
+                                bounds=obs.Q_BOUNDS)
+                    obs.set_gauge("solver.n_active", n_active_i)
+                    obs.set_gauge("solver.kkt", kkt_f)
+                    if prev_active is not None and n_active_i != prev_active:
+                        if n_active_i < prev_active:
+                            obs.inc("solver.shrink_events",
+                                    prev_active - n_active_i)
+                        else:
+                            obs.inc("solver.unshrink_events",
+                                    n_active_i - prev_active)
+                prev_active = n_active_i
+                t_last = t_now
+                if callback is not None:
+                    callback(k, w, f, kkt_f, mean_q_f)
+                # non-finite detector (DESIGN.md section 16.3): always
+                # on, free on the hot path (f/kkt are already host floats)
+                finite = np.isfinite(f) and np.isfinite(kkt_f)
+                if finite and check_finite_w:
+                    finite = bool(jnp.all(jnp.isfinite(w)))
+                if not finite:
+                    diverged = nonfinite = True
+                    obs.inc("solver.nonfinite_trips")
+                    obs.instant("engine.nonfinite_guard",
+                                {"k": k, "objective": f, "kkt": kkt_f})
+                    postmortem = _build_postmortem(hist, aux_q, aux_alpha,
+                                                   k)
+                    # roll the carry back to the last good iterate: the
+                    # poisoned state must never leak into warm starts,
+                    # checkpoints or the returned weights
+                    w, z, key, active = prev_state
+                    f = f_good
+                    break
+                f_good = f
+                if state_callback is not None:
+                    state_callback(k, EngineState(w, z, key, active), f,
+                                   kkt_f)
+                if divergence_guard is not None and divergence_guard(f):
+                    diverged = True
+                    obs.inc("solver.divergence_trips")
+                    obs.instant("engine.divergence_guard",
+                                {"k": k, "objective": f})
+                    postmortem = _build_postmortem(hist, aux_q, aux_alpha,
+                                                   k)
+                    break
+                if kkt_f <= tol_kkt:
+                    converged = True
+                    break
+                if f_star is not None and tol_rel_obj > 0:
+                    if (f - f_star) <= tol_rel_obj * abs(f_star):
+                        converged = True
+                        break
     history = SolveHistory(
         **{k_: np.asarray(v) for k_, v in hist.items()},
         bundle_q=np.asarray(aux_q) if aux_q else None,

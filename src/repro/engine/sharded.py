@@ -267,81 +267,98 @@ def make_sharded_outer(cfg: ShardedPCDNConfig, mesh: Mesh,
             scope step; the per-sample passes between them touch only
             the bundle's shard-local row support."""
             w_l, z_l = carry
-            rB, vB = gather_local(idx)
-            w_B, _ = B.gather_vec(w_l, idx)
-            support, pos = padded_row_support(rB, s_l)
-            z_R = jnp.take(z_l, support, mode="fill", fill_value=0)
-            y_R = jnp.take(y_l, support, mode="fill", fill_value=1)
-            u_R = c * loss.dz(z_R, y_R)
-            v_R = c * loss.d2z(z_R, y_R)
-            if cfg.use_kernels:
-                # pos is the support-local row id array: same kernel,
-                # u/v handed over in support order (all gathers in
-                # bounds; padding entries carry value 0)
-                _, g_part, h_part = kops.pcdn_sparse_direction(
-                    pos, vB, u_R, v_R, w_B, l2=0.0)
-            else:
-                g_part = jnp.sum(jnp.take(u_R, pos) * vB, axis=1)
-                h_part = jnp.sum(jnp.take(v_R, pos) * jnp.square(vB),
-                                 axis=1)
-            # -- phase 1: grad/hess psum over sample shards (unchanged)
-            if cfg.fuse_collectives:
-                gh = jax.lax.psum(jnp.concatenate([g_part, h_part]),
-                                  data_axes)
-                g, h = gh[:P_local], gh[P_local:]
-            else:
-                g = jax.lax.psum(g_part, data_axes)
-                h = jax.lax.psum(h_part, data_axes)
-            if cfg.elastic_net_l2:
-                g = g + cfg.elastic_net_l2 * w_B
-                h = h + cfg.elastic_net_l2
-            h = jnp.maximum(h, HESSIAN_FLOOR)
-            d = newton_direction(g, h, w_B)
-            # -- phase 2: model axis has size 1, so the margin-delta
-            # psum is the identity and only the scalar Delta crosses it;
-            # the (s_l,) dense delta is never built.
-            Delta = jax.lax.psum(delta_decrement(g, h, w_B, d, gamma),
-                                 model_axis)
-            delta_R = jnp.zeros_like(z_R).at[pos].add(vB * d[:, None])
-            # -- phase 3: the SAME (Q,) all-axes psum, loss part now
-            # reduced over the support rows only
-            zq = z_R[None, :] + alphas[:, None] * delta_R[None, :]
-            loss_part = c * jnp.sum(
-                loss.value(zq, y_R[None, :]) -
-                loss.value(z_R, y_R)[None, :], axis=-1)
-            l1_part = (jnp.sum(
-                jnp.abs(w_B[None, :] + alphas[:, None] * d[None, :]),
-                axis=-1) - jnp.sum(jnp.abs(w_B)))
-            fused = loss_part / jnp.asarray(n_model, z_l.dtype) + \
-                l1_part / jnp.asarray(n_data, z_l.dtype)
-            f_deltas = jax.lax.psum(fused, cfg.all_axes)
-            res = select_first_satisfying(f_deltas, alphas, Delta, sigma)
-            w_l = B.scatter_add(w_l, idx, res.alpha * d)
-            z_l = z_l.at[support].add(res.alpha * delta_R, mode="drop")
+            with jax.named_scope("pcdn.direction"):
+                rB, vB = gather_local(idx)
+                w_B, _ = B.gather_vec(w_l, idx)
+                support, pos = padded_row_support(rB, s_l)
+                z_R = jnp.take(z_l, support, mode="fill", fill_value=0)
+                y_R = jnp.take(y_l, support, mode="fill", fill_value=1)
+                u_R = c * loss.dz(z_R, y_R)
+                v_R = c * loss.d2z(z_R, y_R)
+                if cfg.use_kernels:
+                    # pos is the support-local row id array: same
+                    # kernel, u/v handed over in support order (all
+                    # gathers in bounds; padding entries carry value 0)
+                    _, g_part, h_part = kops.pcdn_sparse_direction(
+                        pos, vB, u_R, v_R, w_B, l2=0.0)
+                else:
+                    g_part = jnp.sum(jnp.take(u_R, pos) * vB, axis=1)
+                    h_part = jnp.sum(jnp.take(v_R, pos) * jnp.square(vB),
+                                     axis=1)
+                # -- phase 1: grad/hess psum over sample shards
+                if cfg.fuse_collectives:
+                    gh = jax.lax.psum(jnp.concatenate([g_part, h_part]),
+                                      data_axes)
+                    g, h = gh[:P_local], gh[P_local:]
+                else:
+                    g = jax.lax.psum(g_part, data_axes)
+                    h = jax.lax.psum(h_part, data_axes)
+                if cfg.elastic_net_l2:
+                    g = g + cfg.elastic_net_l2 * w_B
+                    h = h + cfg.elastic_net_l2
+                h = jnp.maximum(h, HESSIAN_FLOOR)
+                d = newton_direction(g, h, w_B)
+                # -- phase 2: model axis has size 1, so the margin-delta
+                # psum is the identity and only the scalar Delta crosses
+                # it; the (s_l,) dense delta is never built.
+                Delta = jax.lax.psum(delta_decrement(g, h, w_B, d, gamma),
+                                     model_axis)
+            with jax.named_scope("pcdn.linesearch"):
+                delta_R = jnp.zeros_like(z_R).at[pos].add(vB * d[:, None])
+                # -- phase 3: the SAME (Q,) all-axes psum, loss part now
+                # reduced over the support rows only
+                zq = z_R[None, :] + alphas[:, None] * delta_R[None, :]
+                loss_part = c * jnp.sum(
+                    loss.value(zq, y_R[None, :]) -
+                    loss.value(z_R, y_R)[None, :], axis=-1)
+                l1_part = (jnp.sum(
+                    jnp.abs(w_B[None, :] + alphas[:, None] * d[None, :]),
+                    axis=-1) - jnp.sum(jnp.abs(w_B)))
+                fused = loss_part / jnp.asarray(n_model, z_l.dtype) + \
+                    l1_part / jnp.asarray(n_data, z_l.dtype)
+                f_deltas = jax.lax.psum(fused, cfg.all_axes)
+                res = select_first_satisfying(f_deltas, alphas, Delta,
+                                              sigma)
+            with jax.named_scope("pcdn.update"):
+                w_l = B.scatter_add(w_l, idx, res.alpha * d)
+                z_l = z_l.at[support].add(res.alpha * delta_R, mode="drop")
             return (w_l, z_l), (res.n_steps, res.alpha)
 
         def bundle_step(carry, idx):
             w_l, z_l = carry
-            slab = gather_local(idx)
-            w_B, _ = B.gather_vec(w_l, idx)
-            u = c * loss.dz(z_l, y_l)
-            v = c * loss.d2z(z_l, y_l)
-            g_part, h_part = grad_hess_parts(slab, u, v, w_B)
-            # -- phase 1: grad/hess psum over sample shards
-            if cfg.fuse_collectives:
-                gh = jax.lax.psum(jnp.concatenate([g_part, h_part]),
-                                  data_axes)
-                g, h = gh[:P_local], gh[P_local:]
-            else:  # baseline: two separate collectives
-                g = jax.lax.psum(g_part, data_axes)
-                h = jax.lax.psum(h_part, data_axes)
-            if cfg.elastic_net_l2:
-                g = g + cfg.elastic_net_l2 * w_B
-                h = h + cfg.elastic_net_l2
-            h = jnp.maximum(h, HESSIAN_FLOOR)
-            d = newton_direction(g, h, w_B)
-            # Delta (Eq. 7) sums over the *global* bundle -> psum over model
-            Delta_part = delta_decrement(g, h, w_B, d, gamma)
+            with jax.named_scope("pcdn.direction"):
+                slab = gather_local(idx)
+                w_B, _ = B.gather_vec(w_l, idx)
+                u = c * loss.dz(z_l, y_l)
+                v = c * loss.d2z(z_l, y_l)
+                g_part, h_part = grad_hess_parts(slab, u, v, w_B)
+                # -- phase 1: grad/hess psum over sample shards
+                if cfg.fuse_collectives:
+                    gh = jax.lax.psum(jnp.concatenate([g_part, h_part]),
+                                      data_axes)
+                    g, h = gh[:P_local], gh[P_local:]
+                else:  # baseline: two separate collectives
+                    g = jax.lax.psum(g_part, data_axes)
+                    h = jax.lax.psum(h_part, data_axes)
+                if cfg.elastic_net_l2:
+                    g = g + cfg.elastic_net_l2 * w_B
+                    h = h + cfg.elastic_net_l2
+                h = jnp.maximum(h, HESSIAN_FLOOR)
+                d = newton_direction(g, h, w_B)
+                # Delta (Eq. 7) sums over the *global* bundle -> psum
+                # over model
+                Delta_part = delta_decrement(g, h, w_B, d, gamma)
+            with jax.named_scope("pcdn.linesearch"):
+                delta_z, alpha, n_steps = line_search(slab, z_l, w_B, d,
+                                                      Delta_part)
+            with jax.named_scope("pcdn.update"):
+                w_l = B.scatter_add(w_l, idx, alpha * d)
+                z_l = z_l + alpha * delta_z
+            return (w_l, z_l), (n_steps, alpha)
+
+        def line_search(slab, z_l, w_B, d, Delta_part):
+            """X_B d, the phase-2 psum and the Armijo search of the
+            full-scope step -> (delta_z, alpha, n_steps)."""
             dz_part = margin_delta_part(slab, d)           # (s_l,)
             # -- phase 2: margins of the bundle step (+ Delta when fused)
             if cfg.fuse_collectives:
@@ -395,9 +412,7 @@ def make_sharded_outer(cfg: ShardedPCDNConfig, mesh: Mesh,
                                  jnp.asarray(False)))
                 alpha = jnp.where(ok, alpha, 0.0)
                 n_steps = q
-            w_l = B.scatter_add(w_l, idx, alpha * d)
-            z_l = z_l + alpha * delta_z
-            return (w_l, z_l), (n_steps, alpha)
+            return delta_z, alpha, n_steps
 
         step_fn = bundle_step_support if use_support else bundle_step
 
@@ -438,30 +453,33 @@ def make_sharded_outer(cfg: ShardedPCDNConfig, mesh: Mesh,
                 aux_alpha = step_alphas.astype(z_l.dtype)
 
         # diagnostics: objective + FULL-set KKT violation (replicated)
-        f_loss = jax.lax.psum(c * jnp.sum(loss.value(z_l, y_l)), data_axes)
-        f_l1 = jax.lax.psum(jnp.sum(jnp.abs(w_l)), model_axis)
-        f = f_loss + f_l1
-        if cfg.elastic_net_l2:
-            f = f + 0.5 * cfg.elastic_net_l2 * jax.lax.psum(
-                jnp.sum(jnp.square(w_l)), model_axis)
-        # full local gradient for KKT: (n_local,) psum over data
-        u = c * loss.dz(z_l, y_l)
-        g_full = jax.lax.psum(full_grad_part(u), data_axes)
-        if cfg.elastic_net_l2:
-            g_full = g_full + cfg.elastic_net_l2 * w_l
-        viol = jnp.abs(jnp.where(
-            w_l > 0, g_full + 1.0,
-            jnp.where(w_l < 0, g_full - 1.0,
-                      jnp.maximum(jnp.abs(g_full) - 1.0, 0.0))))
-        kkt = jax.lax.pmax(jnp.max(viol), cfg.all_axes)
-        if cfg.shrink:
-            interior = (w_l == 0) & (jnp.abs(g_full) < 1.0 - cfg.shrink_tol)
-            active_l = active_l & ~interior
-            active_l = active_l | (recheck & (viol > cfg.tol_kkt))
-        nnz = jax.lax.psum(jnp.sum((w_l != 0).astype(jnp.int32)),
-                           model_axis)
-        n_active = jax.lax.psum(jnp.sum(active_l.astype(jnp.int32)),
-                                model_axis)
+        with jax.named_scope("pcdn.kkt"):
+            f_loss = jax.lax.psum(c * jnp.sum(loss.value(z_l, y_l)),
+                                  data_axes)
+            f_l1 = jax.lax.psum(jnp.sum(jnp.abs(w_l)), model_axis)
+            f = f_loss + f_l1
+            if cfg.elastic_net_l2:
+                f = f + 0.5 * cfg.elastic_net_l2 * jax.lax.psum(
+                    jnp.sum(jnp.square(w_l)), model_axis)
+            # full local gradient for KKT: (n_local,) psum over data
+            u = c * loss.dz(z_l, y_l)
+            g_full = jax.lax.psum(full_grad_part(u), data_axes)
+            if cfg.elastic_net_l2:
+                g_full = g_full + cfg.elastic_net_l2 * w_l
+            viol = jnp.abs(jnp.where(
+                w_l > 0, g_full + 1.0,
+                jnp.where(w_l < 0, g_full - 1.0,
+                          jnp.maximum(jnp.abs(g_full) - 1.0, 0.0))))
+            kkt = jax.lax.pmax(jnp.max(viol), cfg.all_axes)
+            if cfg.shrink:
+                interior = (w_l == 0) & (jnp.abs(g_full)
+                                         < 1.0 - cfg.shrink_tol)
+                active_l = active_l & ~interior
+                active_l = active_l | (recheck & (viol > cfg.tol_kkt))
+            nnz = jax.lax.psum(jnp.sum((w_l != 0).astype(jnp.int32)),
+                               model_axis)
+            n_active = jax.lax.psum(jnp.sum(active_l.astype(jnp.int32)),
+                                    model_axis)
         base = (w_l, z_l, f, kkt, nnz, mean_q, active_l, n_active)
         if cfg.record_aux:
             # q/alpha come out of the all-axes phase-3 psum: replicated

@@ -366,69 +366,69 @@ def tune(kernel: str, runner: Callable[[dict], Callable], bucket, dtype,
     trajectory). The DEFAULT config is always measured, so the recorded
     winner is never slower than the default by construction.
     """
-    t_tune = time.perf_counter_ns()
-    default = dict(DEFAULTS[kernel])
-    table: List[dict] = []
-    measured: Dict[str, float] = {}
+    t_tune = time.perf_counter()
+    with obs.span("autotune.tune") as tune_span:
+        default = dict(DEFAULTS[kernel])
+        table: List[dict] = []
+        measured: Dict[str, float] = {}
 
-    def key_of(cfg: dict) -> str:
-        return json.dumps(cfg, sort_keys=True)
+        def key_of(cfg: dict) -> str:
+            return json.dumps(cfg, sort_keys=True)
 
-    def measure(cfg: dict) -> Optional[float]:
-        k = key_of(cfg)
-        if k in measured:
-            return measured[k]
-        us = _measure(runner, cfg, repeats)
-        if us is not None:
-            measured[k] = us
-            table.append({"config": dict(cfg), "us": us})
-        return us
+        def measure(cfg: dict) -> Optional[float]:
+            k = key_of(cfg)
+            if k in measured:
+                return measured[k]
+            us = _measure(runner, cfg, repeats)
+            if us is not None:
+                measured[k] = us
+                table.append({"config": dict(cfg), "us": us})
+            return us
 
-    default_us = measure(default)
-    if default_us is None:
-        raise RuntimeError(
-            f"autotune[{kernel}]: the default config {default} failed to "
-            f"run — nothing to tune against")
+        default_us = measure(default)
+        if default_us is None:
+            raise RuntimeError(
+                f"autotune[{kernel}]: the default config {default} failed to "
+                f"run — nothing to tune against")
 
-    trajectory = [{"config": dict(default), "us": default_us}]
-    if strategy == "exhaustive":
-        for cfg in candidate_configs(kernel):
-            measure(cfg)
-        best = min(table, key=lambda r: r["us"])
-        trajectory.append({"config": dict(best["config"]),
-                           "us": best["us"]})
-    elif strategy == "hillclimb":
-        space = SEARCH_SPACES[kernel]
-        current, current_us = dict(default), default_us
-        improved = True
-        while improved:
-            improved = False
-            for axis in sorted(space):
-                for v in space[axis]:
-                    if current.get(axis) == v:
-                        continue
-                    cand = dict(current)
-                    cand[axis] = v
-                    us = measure(cand)
-                    if us is not None and us < current_us:
-                        current, current_us = cand, us
-                        trajectory.append({"config": dict(cand), "us": us})
-                        improved = True
-        best = {"config": current, "us": current_us}
-    else:
-        raise ValueError(f"unknown strategy {strategy!r}")
+        trajectory = [{"config": dict(default), "us": default_us}]
+        if strategy == "exhaustive":
+            for cfg in candidate_configs(kernel):
+                measure(cfg)
+            best = min(table, key=lambda r: r["us"])
+            trajectory.append({"config": dict(best["config"]),
+                               "us": best["us"]})
+        elif strategy == "hillclimb":
+            space = SEARCH_SPACES[kernel]
+            current, current_us = dict(default), default_us
+            improved = True
+            while improved:
+                improved = False
+                for axis in sorted(space):
+                    for v in space[axis]:
+                        if current.get(axis) == v:
+                            continue
+                        cand = dict(current)
+                        cand[axis] = v
+                        us = measure(cand)
+                        if us is not None and us < current_us:
+                            current, current_us = cand, us
+                            trajectory.append({"config": dict(cand), "us": us})
+                            improved = True
+            best = {"config": current, "us": current_us}
+        else:
+            raise ValueError(f"unknown strategy {strategy!r}")
 
-    result = TuneResult(kernel=kernel, config=dict(best["config"]),
-                        us=float(best["us"]), default_us=float(default_us),
-                        table=tuple(table), trajectory=tuple(trajectory))
-    if persist:
-        record(kernel, bucket, dtype, result.config, us=result.us,
-               default_us=result.default_us, backend=backend)
-    t_done = time.perf_counter_ns()
+        result = TuneResult(kernel=kernel, config=dict(best["config"]),
+                            us=float(best["us"]), default_us=float(default_us),
+                            table=tuple(table), trajectory=tuple(trajectory))
+        if persist:
+            record(kernel, bucket, dtype, result.config, us=result.us,
+                   default_us=result.default_us, backend=backend)
+        if tune_span is not None:
+            tune_span.set_metadata(kernel=kernel, strategy=strategy,
+                                   candidates=len(table),
+                                   speedup=result.speedup)
     obs.inc("autotune.tunes")
-    obs.observe("autotune.tune_seconds", (t_done - t_tune) / 1e9)
-    obs.complete("autotune.tune", "kernels", t_tune, t_done,
-                 args={"kernel": kernel, "strategy": strategy,
-                       "candidates": len(table),
-                       "speedup": result.speedup})
+    obs.observe("autotune.tune_seconds", time.perf_counter() - t_tune)
     return result

@@ -88,7 +88,7 @@ def _launch_span(kernel: str, impl: str, x: Array):
     if (obs.metrics_enabled() or obs.trace_enabled()) \
             and not isinstance(x, jax.core.Tracer):
         obs.inc(f"kernels.{kernel}.launches")
-        return obs.span(f"kernels.{kernel}", "kernels", args={"impl": impl})
+        return obs.span(f"kernels.{kernel}.{impl}")
     return obs.trace._NULL_SPAN
 
 

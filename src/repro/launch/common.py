@@ -12,6 +12,7 @@ import argparse
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -121,10 +122,12 @@ def add_obs_args(ap: argparse.ArgumentParser):
                          "JSONL run record (counters, gauges, p50/p99 "
                          "histograms) to this file on exit; "
                          "REPRO_METRICS=off force-disables")
-    ap.add_argument("--trace-out", default=None, metavar="JSON",
-                    help="record a Chrome-trace / Perfetto trace-event "
-                         "file of the run (load at ui.perfetto.dev); "
-                         "validate with `python -m repro.obs.validate`")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="record a jax.profiler trace of the run under "
+                         "DIR: the program's spans and the device "
+                         "operations on one clock (the .xplane.pb, and "
+                         "a perfetto_trace.json.gz that ui.perfetto.dev "
+                         "loads)")
 
 
 def add_diag_args(ap: argparse.ArgumentParser):
@@ -227,6 +230,14 @@ def write_diag(args, report: dict, design=None, tol_kkt=None) -> None:
     print(f"[diag] health report written to {args.diag_out}")
 
 
+# The persistent compile cache keys a program without its op metadata, so
+# a traced run could load an executable compiled without the `pcdn.*`
+# scopes (or under older names) and its device operations would name no
+# phase. A traced run keys the cache by the metadata too.
+_METADATA_KEY = "jax_compilation_cache_include_metadata_in_key"
+_metadata_key_before = False
+
+
 def setup_obs(args) -> None:
     """Switch the telemetry planes on per the CLI flags (before any
     instrumented work runs)."""
@@ -234,7 +245,13 @@ def setup_obs(args) -> None:
         obs.registry.enable()
         obs.registry.reset()
     if getattr(args, "trace_out", None):
-        obs.trace.enable(process_name="repro")
+        global _metadata_key_before
+        _metadata_key_before = getattr(jax.config, _METADATA_KEY)
+        jax.config.update(_METADATA_KEY, True)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0    # spans and device ops, not each call
+        jax.profiler.start_trace(args.trace_out, create_perfetto_trace=True,
+                                 profiler_options=opts)
 
 
 def finish_obs(args, meta: dict | None = None) -> None:
@@ -244,8 +261,9 @@ def finish_obs(args, meta: dict | None = None) -> None:
         print(f"[obs] metrics appended to {args.metrics_out}")
         obs.registry.disable()
     if getattr(args, "trace_out", None):
-        if obs.trace.save(args.trace_out):
-            print(f"[obs] trace written to {args.trace_out}")
+        jax.profiler.stop_trace()
+        jax.config.update(_METADATA_KEY, _metadata_key_before)
+        print(f"[obs] profiler trace written under {args.trace_out}")
 
 
 def load_dataset(args, with_test: bool = False):
@@ -287,11 +305,12 @@ def build_pcdn_config(args, **overrides) -> PCDNConfig:
 
 def _record_aux(args) -> bool:
     """Per-bundle (q, alpha) aux outputs ride along exactly when the CLI
-    asked for telemetry OR diagnostics (the health report's backtrack
-    forensics consume them) — without the flags the compiled iteration
-    stays byte-identical to the uninstrumented solver (DESIGN.md 13.2)."""
+    asked for metrics OR diagnostics (the histograms and the health
+    report's backtrack forensics consume them) — without the flags the
+    compiled iteration stays byte-identical to the uninstrumented solver
+    (DESIGN.md 13.2). A profiler trace (`--trace-out`) records the
+    program users run."""
     return bool(getattr(args, "metrics_out", None)
-                or getattr(args, "trace_out", None)
                 or getattr(args, "diag_out", None))
 
 

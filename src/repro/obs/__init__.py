@@ -1,13 +1,13 @@
 """Unified telemetry subsystem (DESIGN.md section 13).
 
-Two data planes, one enable story:
+Two data planes:
 
   * `repro.obs.registry` — process-wide metrics (counters, gauges,
     fixed-bucket histograms) for the host-side control plane. Strictly
     one boolean check when disabled.
-  * `repro.obs.trace`    — Chrome-trace / Perfetto trace-event writer
-    with span helpers ("X" complete events on named tracks) and a
-    schema validator.
+  * `repro.obs.trace`    — program spans as `jax.profiler`
+    annotations, recorded while a profiler session is active, on the
+    same clock as the device operations. One check when not tracing.
 
 Device-side solver signals (per-bundle accepted alpha, backtrack depth
 q^t, active-set size) do NOT go through host callbacks: the engine
@@ -17,9 +17,9 @@ the registry is enabled, into histograms) at the per-iteration sync it
 already performs. With `record_aux=False` the compiled step is
 byte-identical to the uninstrumented solver.
 
-Convenience facade: `obs.enable(metrics=..., trace=...)` switches both
-planes; the module-level helpers (`inc`, `observe`, `span`, ...) proxy
-to the respective plane's zero-cost gate.
+Convenience facade: `obs.enable()` switches the metrics plane on (the
+trace plane follows the profiler); the module-level helpers (`inc`,
+`observe`, `span`, ...) proxy to the respective plane's zero-cost gate.
 """
 from __future__ import annotations
 
@@ -28,32 +28,26 @@ from repro.obs.registry import (ALPHA_BOUNDS, LATENCY_BOUNDS_S, Q_BOUNDS,
                                 Histogram, Registry, get_registry, inc,
                                 observe, observe_many, set_gauge,
                                 write_metrics)
-from repro.obs.trace import (TraceWriter, complete, counter, instant, span,
-                             validate_trace, validate_trace_file)
+from repro.obs.trace import instant, span
 
 __all__ = [
-    "registry", "trace", "Registry", "Histogram", "TraceWriter",
+    "registry", "trace", "Registry", "Histogram",
     "LATENCY_BOUNDS_S", "Q_BOUNDS", "ALPHA_BOUNDS",
     "inc", "observe", "observe_many", "set_gauge", "write_metrics",
-    "span", "complete", "instant", "counter",
-    "validate_trace", "validate_trace_file",
+    "span", "instant",
     "enable", "disable", "metrics_enabled", "trace_enabled",
 ]
 
 
-def enable(metrics: bool = True, trace_: bool = False,
-           process_name: str = "repro") -> None:
-    """Switch the telemetry planes on. REPRO_METRICS=off still wins for
-    the metrics plane (registry.env_force_off)."""
-    if metrics:
-        registry.enable()
-    if trace_:
-        trace.enable(process_name)
+def enable() -> None:
+    """Switch the metrics plane on. REPRO_METRICS=off still wins
+    (registry.env_force_off). Spans need no switch: they record while a
+    profiler session is active."""
+    registry.enable()
 
 
 def disable() -> None:
     registry.disable()
-    trace.disable()
 
 
 def metrics_enabled() -> bool:

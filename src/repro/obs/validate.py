@@ -1,18 +1,13 @@
-"""Telemetry-artifact schema validator CLI (the CI gate):
+"""Metrics-log schema validator CLI (the CI gate):
 
-    python -m repro.obs.validate /tmp/trace.json /tmp/run.jsonl [...]
+    python -m repro.obs.validate /tmp/run.jsonl [...]
 
-Validates both telemetry planes by file extension:
-
-* ``*.jsonl`` — metrics run-record logs (``--metrics-out``): every line
-  must be one self-contained ``{ts, meta..., metrics: {counters,
-  gauges, histograms}}`` record per the `repro.obs.registry` contract —
-  numeric counter/gauge values, histogram dicts with consistent
-  bounds/counts (len(counts) == len(bounds)+1, sum(counts) == count).
-* anything else — Chrome-trace JSON per the contract of
-  `repro.obs.trace`: required ph/ts/dur fields, known phases, and
-  properly nested (never partially overlapping) "X" spans on every
-  (pid, tid) track.
+Validates metrics run-record logs (``--metrics-out``): every line must
+be one self-contained ``{ts, meta..., metrics: {counters, gauges,
+histograms}}`` record per the `repro.obs.registry` contract — numeric
+counter/gauge values, histogram dicts with consistent bounds/counts
+(len(counts) == len(bounds)+1, sum(counts) == count). Traces
+(``--trace-out DIR``) are the profiler's own files and need no check.
 
 Exit code 0 iff every file validates.
 """
@@ -20,8 +15,6 @@ from __future__ import annotations
 
 import json
 import sys
-
-from repro.obs.trace import validate_trace_file
 
 # every histogram dict the registry snapshot writes carries exactly
 # these keys (registry.Histogram.as_dict)
@@ -123,24 +116,18 @@ def validate_metrics_file(path: str) -> int:
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
     if not argv:
-        print("usage: python -m repro.obs.validate TRACE.json|RUN.jsonl "
-              "[...]", file=sys.stderr)
+        print("usage: python -m repro.obs.validate RUN.jsonl [...]",
+              file=sys.stderr)
         return 2
     status = 0
     for path in argv:
-        kind = "metrics" if path.endswith(".jsonl") else "trace"
         try:
-            if kind == "metrics":
-                n = validate_metrics_file(path)
-                unit = "records"
-            else:
-                n = validate_trace_file(path)
-                unit = "events"
+            n = validate_metrics_file(path)
         except (OSError, ValueError) as exc:
             print(f"[obs.validate] {path}: INVALID — {exc}", file=sys.stderr)
             status = 1
         else:
-            print(f"[obs.validate] {path}: OK ({n} {unit})")
+            print(f"[obs.validate] {path}: OK ({n} records)")
     return status
 
 

@@ -155,23 +155,24 @@ def run_path(problem: Optional[L1Problem], cfg: PathConfig,
     t_total0 = time.perf_counter()
     for i in range(i_start, len(cs)):
         c = cs[i]
-        t0_ns = time.perf_counter_ns()
         t0 = time.perf_counter()
-        if not cfg.warm_start:
-            state = backend.init_state()
-        else:
-            # refresh margins from w once per point: O(one matvec), stops
-            # f32 z-drift from accumulating across the whole sweep
-            state = state._replace(z=backend.margins(state.w))
-        state, res = engine_loop.run_outer_loop(
-            outer_fn, state, float(c),
-            max_outer=solver.max_outer, tol_kkt=solver.tol_kkt,
-            recheck_every=solver.recheck_every,
-            tol_rel_obj=solver.tol_rel_obj, callback=callback)
+        with obs.span("path.point") as point_span:
+            if not cfg.warm_start:
+                state = backend.init_state()
+            else:
+                # refresh margins from w once per point: O(one matvec),
+                # stops f32 z-drift from accumulating across the sweep
+                state = state._replace(z=backend.margins(state.w))
+            state, res = engine_loop.run_outer_loop(
+                outer_fn, state, float(c),
+                max_outer=solver.max_outer, tol_kkt=solver.tol_kkt,
+                recheck_every=solver.recheck_every,
+                tol_rel_obj=solver.tol_rel_obj, callback=callback)
+            if point_span is not None:
+                point_span.set_metadata(i=i, c=float(c),
+                                        n_outer=res.n_outer,
+                                        converged=res.converged)
         seconds = time.perf_counter() - t0
-        obs.complete("path.point", "path", t0_ns, time.perf_counter_ns(),
-                     args={"i": i, "c": float(c), "n_outer": res.n_outer,
-                           "converged": res.converged})
         obs.inc("path.points")
         w_host = backend.host_weights(state.w)
         val_acc = (validation_accuracy(val_design, val_y, w_host)

@@ -167,10 +167,9 @@ class MicroBatcher:
             run = lambda: margins_padded_csc(self.bank, packed,
                                              use_kernels=self.use_kernels)
         st = self._stats[bucket]
-        t0_ns = time.perf_counter_ns()
         t0 = time.perf_counter()
-        z = run()
-        z = np.asarray(z)              # blocks until the device is done
+        with obs.span("serve.chunk"):
+            z = np.asarray(run())      # blocks until the device is done
         dt = time.perf_counter() - t0
         warm = st.calls > 0
         if warm:
@@ -192,9 +191,6 @@ class MicroBatcher:
             else:
                 obs.inc("serve.compiles")
                 obs.observe("serve.warmup_s", dt)
-        obs.complete("serve.chunk", "serve", t0_ns, time.perf_counter_ns(),
-                     args={"bucket": bucket, "rows": r,
-                           "pad_rows": bucket - r, "warmup": not warm})
         return z[:r]
 
     # -- accounting ----------------------------------------------------------

@@ -185,6 +185,7 @@ class _ModelSlot:
         self.pending: deque = deque()
         self.rows = 0
         self.pad_rows = 0
+        self.queue_wait_s = 0.0        # sum over rows of flush start - submit
         self.flushes = {"full": 0, "deadline": 0, "drain": 0}
         self.slo_violations = 0
         # batch-failure resilience (DESIGN.md section 16.6)
@@ -199,6 +200,7 @@ class _ModelSlot:
         return {
             "version": self.version, "installs": self.installs,
             "rows": self.rows, "pad_rows": self.pad_rows,
+            "queue_wait_s": self.queue_wait_s,
             "queue_depth": len(self.pending),
             "flushes": dict(self.flushes),
             "slo_violations": self.slo_violations,
@@ -331,39 +333,39 @@ class ServeLoop:
         compiles; seed each slot's latency model with a measured
         post-compile call per bucket."""
         before = sum(scorer_cache_sizes().values())
-        t0_ns = time.perf_counter_ns()
-        for slot in self._slots.values():
-            b0 = slot.bank
-            # round-trip the initial bank through the install program
-            # (via copies — an array cannot be donated AND read as src):
-            # warms the swap path and lands the bank on installed buffers
-            dst = tuple(jnp.array(a) for a in
-                        (b0.idx, b0.val, b0.union_idx, b0.union_val,
-                         b0.bias))
-            arrs = _install(*dst, b0.idx, b0.val, b0.union_idx,
-                            b0.union_val, b0.bias)
-            slot.bank = self._rebind(slot.bank, arrs)
-            for bucket in self.policy.buckets:
-                r = self.route
-                if r == "auto":
-                    r = pick_route(slot.bank.sparsity(), bucket)
-                slot.routes[bucket] = r
-                X = np.zeros((bucket, slot.bank.n_features), np.float32)
-                np.asarray(margins_dense(slot.bank, X,
-                                         use_kernels=self.use_kernels,
-                                         route=r))          # compile call
-                t0 = time.perf_counter()
-                np.asarray(margins_dense(slot.bank, X,
-                                         use_kernels=self.use_kernels,
-                                         route=r))          # steady call
-                slot.latency.observe(bucket, time.perf_counter() - t0)
-        self._warm_compiles = sum(scorer_cache_sizes().values()) - before
+        with obs.span("serve.warmup") as warm_span:
+            for slot in self._slots.values():
+                b0 = slot.bank
+                # round-trip the initial bank through the install program
+                # (via copies — an array cannot be donated AND read as
+                # src): warms the swap path and lands the bank on
+                # installed buffers
+                dst = tuple(jnp.array(a) for a in
+                            (b0.idx, b0.val, b0.union_idx, b0.union_val,
+                             b0.bias))
+                arrs = _install(*dst, b0.idx, b0.val, b0.union_idx,
+                                b0.union_val, b0.bias)
+                slot.bank = self._rebind(slot.bank, arrs)
+                for bucket in self.policy.buckets:
+                    r = self.route
+                    if r == "auto":
+                        r = pick_route(slot.bank.sparsity(), bucket)
+                    slot.routes[bucket] = r
+                    X = np.zeros((bucket, slot.bank.n_features), np.float32)
+                    np.asarray(margins_dense(slot.bank, X,
+                                             use_kernels=self.use_kernels,
+                                             route=r))      # compile call
+                    t0 = time.perf_counter()
+                    np.asarray(margins_dense(slot.bank, X,
+                                             use_kernels=self.use_kernels,
+                                             route=r))      # steady call
+                    slot.latency.observe(bucket, time.perf_counter() - t0)
+            self._warm_compiles = sum(scorer_cache_sizes().values()) - before
+            if warm_span is not None:
+                warm_span.set_metadata(compiles=self._warm_compiles,
+                                       models=len(self._slots))
         if obs.metrics_enabled():
             obs.inc("serve.compiles", self._warm_compiles)
-        obs.complete("serve.warmup", "serve", t0_ns, time.perf_counter_ns(),
-                     args={"compiles": self._warm_compiles,
-                           "models": len(self._slots),
-                           "buckets": list(self.policy.buckets)})
 
     @staticmethod
     def _rebind(template: ModelBank, arrs) -> ModelBank:
@@ -509,20 +511,27 @@ class ServeLoop:
     # -- scheduler thread ----------------------------------------------------
     def _scheduler(self) -> None:
         while True:
-            chunk = None
             with self._work:
-                while True:
+                chunk, wait_s = self._take_locked()
+                if chunk is None and not self._stop:
+                    # one span per idle stretch, however many wake-ups
+                    with obs.span("serve.wait"):
+                        while chunk is None and not self._stop:
+                            self._work.wait(wait_s)
+                            chunk, wait_s = self._take_locked()
+                if chunk is None:
                     self._apply_installs_locked()
-                    now = time.perf_counter()
-                    choice, wait_s = self._next_action_locked(now)
-                    if choice is not None:
-                        chunk = self._pop_locked(*choice)
-                        break
-                    if self._stop:
-                        self._apply_installs_locked()
-                        return
-                    self._work.wait(wait_s)
+                    return
             self._score(*chunk)
+
+    def _take_locked(self):
+        """Apply queued installs, then pop the chunk due now:
+        -> (chunk, None), or (None, seconds to wait; None = until woken)."""
+        self._apply_installs_locked()
+        choice, wait_s = self._next_action_locked(time.perf_counter())
+        if choice is None:
+            return None, wait_s
+        return self._pop_locked(*choice), None
 
     def _next_action_locked(self, now: float):
         """(slot, take, reason) ready to flush, or (None, wait_seconds)."""
@@ -565,98 +574,100 @@ class ServeLoop:
 
     def _score(self, slot: _ModelSlot, reqs, reason: str, bank: ModelBank,
                version: int) -> None:
-        bucket = self.policy.bucket_for(len(reqs))
-        t0_ns = time.perf_counter_ns()
-        t0 = time.perf_counter()
-        z = err = None
-        for attempt in range(1 + self.batch_retries):
-            try:
-                X = self.policy.pad_dense(np.stack([p.x for p in reqs]),
-                                          bucket)
-                z = np.asarray(margins_dense(bank, X,
-                                             use_kernels=self.use_kernels,
-                                             route=slot.routes[bucket]))
-                err = None
-                break
-            except Exception as e:      # bounded in-place retry first
-                err = e
-                if attempt < self.batch_retries:
-                    with self._lock:
-                        slot.retries += 1
-                    if obs.metrics_enabled():
-                        obs.inc("serve.batch_retries")
-        if err is not None:                     # serve on: fail the batch
-            with self._lock:
-                self._errors += len(reqs)
-                slot.failed_batches += 1
-                slot.consecutive_failures += 1
-                if (self.quarantine_after is not None
-                        and slot.consecutive_failures
-                        >= self.quarantine_after
-                        and not slot.quarantined):
-                    slot.quarantined = True
-                    if obs.metrics_enabled():
-                        obs.inc("serve.loop.quarantines")
-                    obs.instant("serve.quarantine", "serve",
-                                args={"model": slot.name,
-                                      "failures":
-                                      slot.consecutive_failures})
-            if obs.metrics_enabled():
-                obs.inc("serve.loop.errors", len(reqs))
-                obs.inc("serve.batch_failures")
-            for p in reqs:
-                p.future._set_error(err)
-            return
-        with self._lock:
-            slot.consecutive_failures = 0
-        t_done = time.perf_counter()
-        dt = t_done - t0
-        with self._lock:
-            slot.latency.observe(bucket, dt)
-            slot.rows += len(reqs)
-            slot.pad_rows += bucket - len(reqs)
-            slot.flushes[reason] += 1
-            hist = slot.compute.get(bucket)
-            if hist is None:
-                hist = slot.compute[bucket] = obs.Histogram(
-                    obs.LATENCY_BOUNDS_S)
-            hist.observe(dt)
-            self._responses += len(reqs)
-            late = sum(1 for p in reqs if t_done > p.deadline)
-            slot.slo_violations += late
-            for p in reqs:
-                slot.e2e.observe(t_done - p.t_submit)
-        if obs.metrics_enabled():
-            obs.inc("serve.loop.responses", len(reqs))
-            obs.inc("serve.loop.rows", len(reqs))
-            obs.inc("serve.loop.pad_rows", bucket - len(reqs))
-            obs.inc(f"serve.loop.flush.{reason}")
-            if late:
-                obs.inc("serve.loop.slo_violations", late)
-            obs.observe(f"serve.latency_s.bucket_{bucket}", dt)
-            for p in reqs:
-                obs.observe("serve.e2e_latency_s", t_done - p.t_submit)
-        obs.complete("serve.flush", "serve", t0_ns, time.perf_counter_ns(),
-                     args={"model": slot.name, "bucket": bucket,
-                           "rows": len(reqs), "pad_rows": bucket - len(reqs),
-                           "reason": reason, "version": version})
-        for i, p in enumerate(reqs):
-            p.future._set(ServeResult(
-                id=p.id, model=slot.name, margins=z[i], version=version,
-                bucket=bucket, flush_reason=reason, t_submit=p.t_submit,
-                t_done=t_done))
+        with obs.span("serve.flush") as flush_span:
+            bucket = self.policy.bucket_for(len(reqs))
+            if flush_span is not None:
+                flush_span.set_metadata(model=slot.name, rows=len(reqs),
+                                        bucket=bucket, reason=reason,
+                                        version=version)
+            t0 = time.perf_counter()
+            z = err = None
+            for attempt in range(1 + self.batch_retries):
+                try:
+                    with obs.span("serve.stack"):
+                        X = self.policy.pad_dense(
+                            np.stack([p.x for p in reqs]), bucket)
+                    with obs.span("serve.score"):
+                        z = np.asarray(margins_dense(
+                            bank, X, use_kernels=self.use_kernels,
+                            route=slot.routes[bucket]))
+                    err = None
+                    break
+                except Exception as e:      # bounded in-place retry first
+                    err = e
+                    if attempt < self.batch_retries:
+                        with self._lock:
+                            slot.retries += 1
+                        if obs.metrics_enabled():
+                            obs.inc("serve.batch_retries")
+            if err is not None:                     # serve on: fail the batch
+                with self._lock:
+                    self._errors += len(reqs)
+                    slot.failed_batches += 1
+                    slot.consecutive_failures += 1
+                    if (self.quarantine_after is not None
+                            and slot.consecutive_failures
+                            >= self.quarantine_after
+                            and not slot.quarantined):
+                        slot.quarantined = True
+                        if obs.metrics_enabled():
+                            obs.inc("serve.loop.quarantines")
+                        obs.instant("serve.quarantine",
+                                    {"model": slot.name,
+                                     "failures": slot.consecutive_failures})
+                if obs.metrics_enabled():
+                    obs.inc("serve.loop.errors", len(reqs))
+                    obs.inc("serve.batch_failures")
+                for p in reqs:
+                    p.future._set_error(err)
+                return
+            t_done = time.perf_counter()
+            with obs.span("serve.respond"):
+                dt = t_done - t0
+                with self._lock:
+                    slot.consecutive_failures = 0
+                    slot.latency.observe(bucket, dt)
+                    slot.rows += len(reqs)
+                    slot.pad_rows += bucket - len(reqs)
+                    slot.queue_wait_s += sum(t0 - p.t_submit for p in reqs)
+                    slot.flushes[reason] += 1
+                    hist = slot.compute.get(bucket)
+                    if hist is None:
+                        hist = slot.compute[bucket] = obs.Histogram(
+                            obs.LATENCY_BOUNDS_S)
+                    hist.observe(dt)
+                    self._responses += len(reqs)
+                    late = sum(1 for p in reqs if t_done > p.deadline)
+                    slot.slo_violations += late
+                    for p in reqs:
+                        slot.e2e.observe(t_done - p.t_submit)
+                if obs.metrics_enabled():
+                    obs.inc("serve.loop.responses", len(reqs))
+                    obs.inc("serve.loop.rows", len(reqs))
+                    obs.inc("serve.loop.pad_rows", bucket - len(reqs))
+                    obs.inc(f"serve.loop.flush.{reason}")
+                    if late:
+                        obs.inc("serve.loop.slo_violations", late)
+                    obs.observe(f"serve.latency_s.bucket_{bucket}", dt)
+                    for p in reqs:
+                        obs.observe("serve.e2e_latency_s", t_done - p.t_submit)
+                for i, p in enumerate(reqs):
+                    p.future._set(ServeResult(
+                        id=p.id, model=slot.name, margins=z[i],
+                        version=version, bucket=bucket, flush_reason=reason,
+                        t_submit=p.t_submit, t_done=t_done))
 
     def _apply_installs_locked(self) -> None:
         while self._installs:
             name, new_bank, ticket = self._installs.popleft()
             slot = self._slots[name]
-            t0_ns = time.perf_counter_ns()
             old = slot.bank
-            arrs = _install(old.idx, old.val, old.union_idx, old.union_val,
-                            old.bias, new_bank.idx, new_bank.val,
-                            new_bank.union_idx, new_bank.union_val,
-                            new_bank.bias)
-            slot.bank = self._rebind(new_bank, arrs)
+            with obs.span("serve.install"):
+                arrs = _install(old.idx, old.val, old.union_idx,
+                                old.union_val, old.bias, new_bank.idx,
+                                new_bank.val, new_bank.union_idx,
+                                new_bank.union_val, new_bank.bias)
+                slot.bank = self._rebind(new_bank, arrs)
             slot.version += 1
             slot.installs += 1
             # a fresh model clears the failure streak and any quarantine
@@ -665,9 +676,6 @@ class ServeLoop:
             ticket.version = slot.version
             if obs.metrics_enabled():
                 obs.inc("serve.loop.installs")
-            obs.complete("serve.install", "serve", t0_ns,
-                         time.perf_counter_ns(),
-                         args={"model": name, "version": slot.version})
             ticket.installed.set()
 
     # -- accounting ----------------------------------------------------------

@@ -100,54 +100,161 @@ def test_write_metrics_jsonl(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# trace units
+# trace plane: program spans as profiler annotations
 
 
-def test_trace_spans_nest_and_validate():
-    tracer = obs.trace.enable(process_name="t")
-    with obs.span("outer", "engine"):
-        with obs.span("inner", "engine", args={"k": 1}):
-            pass
-    obs.instant("mark", "engine")
-    obs.counter("n_active", 5.0, "engine")
-    d = tracer.to_dict()
-    n = obs.validate_trace(d)
-    assert n >= 4  # 2 spans + instant + counter (+ metadata events)
-    names = {e["name"] for e in d["traceEvents"]}
-    assert {"outer", "inner", "mark", "n_active"} <= names
+class _Recorder:
+    """Stands in for `jax.profiler.TraceAnnotation`: records the name of
+    every annotation built, and the metadata attached to it."""
+
+    def __init__(self):
+        self.names, self.metadata = [], {}
+
+    def __call__(self, name, **args):
+        self.names.append(name)
+        rec = self
+
+        class _Span:
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+            def set_metadata(self, **kw):
+                rec.metadata.setdefault(name, []).append(kw)
+        return _Span()
+
+
+def _record_spans(monkeypatch, tracing: bool) -> _Recorder:
+    rec = _Recorder()
+    monkeypatch.setattr(obs.trace, "TraceAnnotation", rec)
+    monkeypatch.setattr(obs.trace, "_tracing", lambda: tracing)
+    return rec
+
+
+def _host_spans(trace_dir) -> list:
+    """(name, start ns, end ns, line) of every host event of the one
+    .xplane.pb under trace_dir whose name is a program span."""
+    import glob
+    from jax.profiler import ProfileData
+    files = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1, files
+    out = []
+    for plane in ProfileData.from_file(files[0]).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            out.extend((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                        line.name) for e in line.events
+                       if e.name.startswith(("engine.", "serve.", "path.")))
+    return out
+
+
+def _profile(trace_dir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(str(trace_dir), profiler_options=opts)
 
 
 def test_trace_disabled_span_is_null():
-    assert obs.trace.get_tracer() is None
-    with obs.span("x", "engine"):
-        pass
-    assert obs.trace.get_tracer() is None
-    assert obs.trace.save("/nonexistent/never-written.json") is False
+    assert not obs.trace_enabled()
+    span = obs.span("x")
+    assert span is obs.trace._NULL_SPAN
+    with span as entered:
+        assert entered is None
 
 
-def test_validate_trace_rejects_garbage():
-    with pytest.raises(ValueError, match="traceEvents"):
-        obs.validate_trace({"events": []})
-    with pytest.raises(ValueError, match="missing required field"):
-        obs.validate_trace({"traceEvents": [{"name": "a", "ph": "X"}]})
-    with pytest.raises(ValueError, match="unknown phase"):
-        obs.validate_trace({"traceEvents": [
-            {"name": "a", "ph": "Z", "ts": 0, "pid": 1, "tid": 1}]})
-    # partial overlap on one track: [0, 10] vs [5, 15]
-    with pytest.raises(ValueError, match="partially overlaps"):
-        obs.validate_trace({"traceEvents": [
-            {"name": "a", "ph": "X", "ts": 0, "dur": 10, "pid": 1, "tid": 1},
-            {"name": "b", "ph": "X", "ts": 5, "dur": 10, "pid": 1, "tid": 1},
-        ]})
+def test_outer_loop_builds_no_annotation_when_off(monkeypatch, problem):
+    """With no profiler session the engine loop builds no annotation and
+    attaches no span arguments: one check per span site."""
+    rec = _record_spans(monkeypatch, tracing=False)
+    res = solve(problem, PCDNConfig(P=32, max_outer=5, tol_kkt=1e-8,
+                                    seed=0))
+    assert res.n_outer == 5
+    assert rec.names == [] and rec.metadata == {}
 
 
-def test_validate_trace_file_roundtrip(tmp_path):
-    obs.trace.enable(process_name="t")
-    with obs.span("s", "main"):
-        pass
-    path = tmp_path / "t.json"
-    assert obs.trace.save(str(path)) is True
-    assert obs.validate_trace_file(str(path)) >= 1
+def test_outer_loop_spans_per_iteration(monkeypatch, problem):
+    rec = _record_spans(monkeypatch, tracing=True)
+    res = solve(problem, PCDNConfig(P=32, max_outer=4, tol_kkt=1e-8,
+                                    seed=0))
+    phases = ["engine.outer", "engine.dispatch", "engine.wait",
+              "engine.pull", "engine.record"]
+    assert rec.names == phases * res.n_outer
+    args = rec.metadata["engine.outer"]
+    assert [a["k"] for a in args] == list(range(res.n_outer))
+    assert args[-1]["objective"] == res.objective
+
+
+def test_profiler_trace_nests_engine_spans(tmp_path, problem):
+    """In a CPU profiler trace each iteration's four phases sit inside
+    its engine.outer span, on the same thread, in order."""
+    cfg = PCDNConfig(P=32, max_outer=3, tol_kkt=1e-8, seed=0)
+    solve(problem, cfg)                        # compile outside the trace
+    with _profile(tmp_path):
+        res = solve(problem, cfg)
+    spans = _host_spans(tmp_path)
+    outers = sorted(s for s in spans if s[0] == "engine.outer")
+    assert len(outers) == res.n_outer
+    for _, a, b, line in outers:
+        inner = sorted((s for s in spans if s[0] != "engine.outer"
+                        and a <= s[1] and s[2] <= b), key=lambda s: s[1])
+        assert [s[0] for s in inner] == ["engine.dispatch", "engine.wait",
+                                         "engine.pull", "engine.record"]
+        assert {s[3] for s in inner} == {line}
+
+
+def test_profiler_trace_holds_serve_spans(tmp_path):
+    """The serving loop's spans reach the profiler from the scheduler
+    thread: warm-up, idle stretches, and each flush with its phases."""
+    from repro.serve.loop import ServeLoop
+    from repro.serve.predict import ModelBank
+    rng = np.random.default_rng(0)
+    W = np.zeros((3, 64), np.float32)
+    W[:, :8] = rng.standard_normal((3, 8))
+    X = rng.standard_normal((20, 64)).astype(np.float32)
+    with _profile(tmp_path):
+        with ServeLoop(ModelBank.from_dense(W, kind="path"),
+                       buckets=(4, 8), default_budget_s=0.02) as loop:
+            futs = loop.submit_many(X)
+            for f in futs:
+                f.result(timeout=30)
+    spans = _host_spans(tmp_path)
+    names = {s[0] for s in spans}
+    assert {"serve.warmup", "serve.wait", "serve.flush", "serve.stack",
+            "serve.score", "serve.respond"} <= names
+    flushes = [s for s in spans if s[0] == "serve.flush"]
+    assert 3 <= len(flushes) <= 20
+    for _, a, b, line in flushes:
+        inner = [s[0] for s in spans if s[0] in ("serve.stack",
+                                                 "serve.score",
+                                                 "serve.respond")
+                 and a <= s[1] and s[2] <= b and s[3] == line]
+        assert inner == ["serve.stack", "serve.score", "serve.respond"]
+
+
+def test_queue_wait_adds_up():
+    """queue_wait_s is the sum over rows of (flush start - submit): with
+    one row per flush, every row's latency less its flush's compute."""
+    from repro.serve.loop import ServeLoop
+    from repro.serve.predict import ModelBank
+    W = np.zeros((2, 32), np.float32)
+    W[:, 0] = 1.0
+    X = np.ones((12, 32), np.float32)
+    with ServeLoop(ModelBank.from_dense(W, kind="path"), buckets=(1,),
+                   default_budget_s=0.01) as loop:
+        stats0 = loop.stats()["models"]["default"]
+        results = [f.result(timeout=30) for f in loop.submit_many(X)]
+    stats = loop.stats()["models"]["default"]
+    assert stats0["queue_wait_s"] == 0.0
+    compute = sum(h.total for h in loop._slots["default"].compute.values())
+    latency = sum(r.latency_s for r in results)
+    assert stats["rows"] == len(results) == 12
+    assert stats["queue_wait_s"] > 0
+    assert stats["queue_wait_s"] == pytest.approx(latency - compute,
+                                                  rel=1e-9, abs=1e-12)
 
 
 # ---------------------------------------------------------------------------
@@ -266,11 +373,11 @@ def test_solver_loop_zero_registry_activity_when_disabled(problem):
     appear as a side effect of solving."""
     solve(problem, PCDNConfig(P=32, max_outer=5, seed=0))
     assert obs.registry.get_registry().empty
-    assert obs.trace.get_tracer() is None
+    assert not obs.trace_enabled()
 
 
 def test_solver_loop_populates_registry_when_enabled(problem):
-    obs.enable(metrics=True)
+    obs.enable()
     cfg = PCDNConfig(P=32, max_outer=8, tol_kkt=1e-8, seed=0,
                      record_aux=True)
     res = solve(problem, cfg)
@@ -304,7 +411,7 @@ def test_wall_clock_monotone_and_sums_to_total(problem):
 
 
 def test_iter_seconds_histogram_consistent_with_wall_time(problem):
-    obs.enable(metrics=True)
+    obs.enable()
     cfg = PCDNConfig(P=32, max_outer=10, tol_kkt=1e-8, seed=0)
     res = solve(problem, cfg)
     h = obs.registry.get_registry().snapshot()[
@@ -323,7 +430,7 @@ def test_divergence_guard_history_consistent():
     X, y, _ = make_classification(300, 200, sparsity=0.0, corr=0.95,
                                   seed=2, row_normalize=False)
     prob = make_problem(X, y, c=1.0)
-    obs.enable(metrics=True)
+    obs.enable()
     res = scdn.solve(prob, SCDNConfig(P_bar=64, max_rounds=30))
     assert res.diverged and not res.converged
     # the aborted loop still records one consistent row per round run
@@ -334,16 +441,14 @@ def test_divergence_guard_history_consistent():
         "counters"]["solver.divergence_trips"] == 1.0
 
 
-def test_divergence_guard_emits_trace_instant():
+def test_divergence_guard_emits_trace_instant(monkeypatch):
     X, y, _ = make_classification(300, 200, sparsity=0.0, corr=0.95,
                                   seed=2, row_normalize=False)
     prob = make_problem(X, y, c=1.0)
-    tracer = obs.trace.enable(process_name="t")
+    rec = _record_spans(monkeypatch, tracing=True)
     scdn.solve(prob, SCDNConfig(P_bar=64, max_rounds=30))
-    events = tracer.to_dict()["traceEvents"]
-    assert any(e["name"] == "engine.divergence_guard" and e["ph"] == "i"
-               for e in events)
-    obs.validate_trace(tracer.to_dict())
+    assert rec.names.count("engine.divergence_guard") == 1
+    assert rec.names.count("engine.divergence_postmortem") == 1
 
 
 def test_lockstep_freeze_bitwise():
@@ -412,7 +517,7 @@ def test_batcher_latency_quantiles_and_counters():
     W = np.zeros((4, 256), np.float32)
     W[:, :8] = rng.standard_normal((4, 8))
     bank = ModelBank.from_dense(W, kind="path")
-    obs.enable(metrics=True, trace_=True)
+    obs.enable()
     b = MicroBatcher(bank, buckets=(8, 32), layout="dense")
     X = rng.standard_normal((64, 256)).astype(np.float32)
     for lo, hi in ((0, 5), (5, 37), (37, 64), (0, 30)):
@@ -425,7 +530,6 @@ def test_batcher_latency_quantiles_and_counters():
     snap = obs.registry.get_registry().snapshot()
     assert snap["counters"]["serve.rows"] == 64 + 30
     assert snap["counters"]["serve.compiles"] == 2  # one per bucket
-    obs.validate_trace(obs.trace.get_tracer().to_dict())
 
 
 def test_batcher_disabled_zero_registry_activity():
@@ -441,7 +545,7 @@ def test_batcher_disabled_zero_registry_activity():
 
 def test_autotune_lookup_counters(monkeypatch, tmp_path):
     from repro.kernels import autotune
-    obs.enable(metrics=True)
+    obs.enable()
     # disabled tuner -> every lookup is a miss ("defaults were used")
     monkeypatch.setenv("REPRO_AUTOTUNE", "off")
     assert autotune.lookup("pcdn_direction", (64, 64), "float32") is None
@@ -455,7 +559,7 @@ def test_kernel_launch_counter_eager_only():
     op traced under jit must not touch the registry from inside tracing
     (that would be a host callback in the compiled path)."""
     from repro.kernels import ops
-    obs.enable(metrics=True)
+    obs.enable()
     XB = jnp.ones((8, 4), jnp.float32)
     u = jnp.full((8,), 0.25, jnp.float32)
     v = jnp.ones((8,), jnp.float32)
@@ -484,12 +588,15 @@ def test_solve_cli_metrics_and_trace(tmp_path):
     ds = tmp_path / "d.svm"
     save_libsvm(str(ds), X, y)
     mpath, tpath, rpath = (str(tmp_path / n) for n in
-                           ("m.jsonl", "t.json", "r.json"))
+                           ("m.jsonl", "trace", "r.json"))
     solve_cli.main(["--dataset", str(ds), "--P", "16", "--max-outer", "10",
                     "--tol", "1e-6", "--c", "5.0",
                     "--metrics-out", mpath, "--trace-out", tpath,
                     "--out", rpath])
-    assert obs.validate_trace_file(tpath) > 0
+    # a profiler trace: the .xplane.pb holds the engine's spans
+    names = [s[0] for s in _host_spans(tpath)]
+    assert names.count("engine.outer") == len(
+        json.load(open(rpath))["history"]["objective"])
     rec = json.loads(open(mpath).read().strip().splitlines()[-1])
     assert rec["cli"] == "solve"
     assert "solver.bundle_q" in rec["metrics"]["histograms"]
@@ -514,14 +621,89 @@ def test_solve_cli_without_flags_records_nothing(tmp_path):
     assert "bundle_q" not in report["history"]
 
 
+def test_trace_out_runs_the_program_users_run(tmp_path):
+    """--trace-out alone compiles no aux outputs (the traced run is the
+    untraced program), and the report CLI reads its Perfetto file."""
+    from repro.diag import report as diag_report
+    from repro.launch import solve as solve_cli
+    from repro.data.libsvm import save_libsvm
+    X, y, _ = make_classification(120, 60, sparsity=0.5, seed=0)
+    ds = tmp_path / "d.svm"
+    save_libsvm(str(ds), X, y)
+    tdir, rpath = str(tmp_path / "trace"), str(tmp_path / "r.json")
+    solve_cli.main(["--dataset", str(ds), "--P", "16", "--max-outer", "5",
+                    "--c", "5.0", "--trace-out", tdir, "--out", rpath])
+    assert "bundle_q" not in json.load(open(rpath))["history"]
+    assert not obs.trace_enabled()
+    md = tmp_path / "health.md"
+    assert diag_report.main(["--trace", tdir, "-o", str(md)]) == 0
+    assert "engine.outer" in md.read_text()
+
+
+_COMPILE_OUTER = r"""
+import argparse, contextlib, sys
+import jax, jax.numpy as jnp
+cache, mode, trace_dir = sys.argv[1:4]
+jax.config.update("jax_compilation_cache_dir", cache)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+if mode == "unscoped":
+    jax.named_scope = lambda name: contextlib.nullcontext()
+from repro.core import PCDNConfig
+from repro.core.design_matrix import DenseDesign
+from repro.core.problem import L1Problem
+from repro.engine.local import LocalBackend
+from repro.launch import common
+
+def outer(X, y, w, z, key, active):
+    prob = L1Problem(design=DenseDesign(X=X), y=y, c=1.0)
+    return LocalBackend(prob, PCDNConfig(P=8)).outer(
+        w, z, key, active, jnp.bool_(True), jnp.float32(1.0))
+
+args = argparse.Namespace(metrics_out=None,
+                          trace_out=trace_dir if mode == "traced" else None)
+common.setup_obs(args)
+f32 = jnp.float32
+text = jax.jit(outer).lower(
+    jax.ShapeDtypeStruct((40, 24), f32), jax.ShapeDtypeStruct((40,), f32),
+    jax.ShapeDtypeStruct((24,), f32), jax.ShapeDtypeStruct((40,), f32),
+    jax.ShapeDtypeStruct((2,), jnp.uint32),
+    jax.ShapeDtypeStruct((24,), jnp.bool_)).compile().as_text()
+common.finish_obs(args)
+print("SCOPED" if "/pcdn.direction/" in text else "UNSCOPED")
+"""
+
+
+def test_trace_out_does_not_load_an_unscoped_executable(tmp_path):
+    """The persistent compile cache keys a program without its op
+    metadata. After a cache was filled by a build without the phase
+    scopes, an untraced run loads that executable (its metadata names no
+    phase), but a --trace-out run compiles one whose does."""
+    repo = os.path.dirname(os.path.dirname(__file__))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+               JAX_PLATFORMS="cpu")
+    cache = str(tmp_path / "cache")
+
+    def run(mode):
+        r = subprocess.run([sys.executable, "-c", _COMPILE_OUTER, cache,
+                            mode, str(tmp_path / "trace")],
+                           capture_output=True, text=True, env=env,
+                           cwd=repo, timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return r.stdout.split()[-1]
+
+    assert run("unscoped") == "UNSCOPED"
+    assert run("plain") == "UNSCOPED"       # loaded from the cache
+    assert run("traced") == "SCOPED"
+
+
 def test_obs_validate_cli(tmp_path):
-    obs.trace.enable(process_name="t")
-    with obs.span("s", "main"):
-        pass
-    good = tmp_path / "good.json"
-    obs.trace.save(str(good))
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"traceEvents": [{"name": "a"}]}))
+    obs.registry.enable()
+    obs.inc("runs")
+    good = tmp_path / "good.jsonl"
+    obs.write_metrics(str(good))
+    bad = tmp_path / "bad.jsonl"
+    bad.write_text(json.dumps({"ts": "t"}) + "\n")
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-m", "repro.obs.validate",
                         str(good)], capture_output=True, text=True,

@@ -124,19 +124,39 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, n):
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, *specs)
 
 
+_OUTER_TEXT = {}
+
+
+def _gisette_outer_text(sharding, use_kernels: bool) -> str:
+    """The compiled text of one local outer iteration at gisette's shape
+    (compiled once per route for the whole module)."""
+    if use_kernels not in _OUTER_TEXT:
+        s, n = GISETTE["s"], GISETTE["n"]
+        cfg = PCDNConfig(P=P, use_kernels=use_kernels)
+
+        def outer(X, y, w, z, key, active):
+            prob = L1Problem(design=DenseDesign(X=X), y=y, c=0.25)
+            return LocalBackend(prob, cfg).outer(w, z, key, active,
+                                                 jnp.bool_(True),
+                                                 jnp.float32(0.25))
+
+        _OUTER_TEXT[use_kernels] = _compiled_text(
+            outer, sharding, ((s, n), f32), ((s,), f32), ((n,), f32),
+            ((s,), f32), ((2,), jnp.uint32), ((n,), jnp.bool_))
+    return _OUTER_TEXT[use_kernels]
+
+
 @pytest.mark.parametrize("use_kernels", [False, True],
                          ids=["xla", "kernels"])
 def test_local_outer_compiles_for_v5e_at_gisette(one_chip, use_kernels):
-    s, n = GISETTE["s"], GISETTE["n"]
-    cfg = PCDNConfig(P=P, use_kernels=use_kernels)
-
-    def outer(X, y, w, z, key, active):
-        prob = L1Problem(design=DenseDesign(X=X), y=y, c=0.25)
-        return LocalBackend(prob, cfg).outer(w, z, key, active,
-                                             jnp.bool_(True),
-                                             jnp.float32(0.25))
-
-    text = _compiled_text(outer, one_chip, ((s, n), f32), ((s,), f32),
-                          ((n,), f32), ((s,), f32), ((2,), jnp.uint32),
-                          ((n,), jnp.bool_))
+    text = _gisette_outer_text(one_chip, use_kernels)
     assert ("tpu_custom_call" in text) == use_kernels
+
+
+@pytest.mark.parametrize("scope", ["pcdn.direction", "pcdn.linesearch",
+                                   "pcdn.update", "pcdn.kkt"])
+def test_compiled_outer_names_its_phases(one_chip, scope):
+    """The bundle phases reach the compiled step's op metadata, which the
+    device trace carries as each operation's `tf_op` path."""
+    text = _gisette_outer_text(one_chip, False)
+    assert f'/{scope}/' in text
