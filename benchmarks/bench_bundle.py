@@ -97,9 +97,9 @@ def bench_components(prob, P=P_BUNDLE, seed=0):
 
     @jax.jit
     def dir_full(w, z, idx):
-        slab = design.gather_slab(idx)
+        slab = prob.gather_slab(idx)    # with the labels, from col_y
         w_B, _ = B.gather_vec(w, idx)
-        g, h = prob.bundle_grad_hess(z, slab, w_B)   # u/v over all s
+        g, h = prob.bundle_grad_hess(z, slab, w_B)   # one gather of z
         return newton_direction(g, h, w_B)
 
     @jax.jit

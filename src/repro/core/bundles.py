@@ -57,9 +57,10 @@ def gather_slab(X: Array, idx: Array) -> tuple[Array, Array]:
 
     idx: (P,) with possible sentinel n. Returns (XB, valid_mask) where
     padded columns are zeroed so they contribute nothing to any reduction.
-    Solvers holding an L1Problem go through design.gather_slab instead
-    (backend-dispatched — DESIGN.md section 7); this raw-array version
-    remains for the sharded dense path, which works on local blocks.
+    Solvers holding an L1Problem go through `L1Problem.gather_slab`
+    instead (backend-dispatched — DESIGN.md section 7); this raw-array
+    version remains for the sharded dense path, which works on local
+    blocks.
     """
     n = X.shape[1]
     valid = idx < n

@@ -29,8 +29,9 @@ ACCUMULATES in f32 — products/sums upcast via
 storage. Solver state (w, z, u, v) stays f32 regardless; only the
 design values and their HBM traffic shrink. Bundle slabs are small
 NamedTuples (`DenseSlab` / `SparseSlab`) produced by `gather_slab` and
-consumed by `slab_grad_hess` / `slab_matvec` — the only three methods the
-inner solver loops touch.
+consumed by `slab_grad_hess` (dense) or `slab_grad_hess_gathered`
+(padded CSC) and `slab_matvec` — the only methods the inner solver loops
+touch.
 
 The k_max trade-off (DESIGN.md section 7.2): memory and gather work scale
 with n * k_max = n * max_j nnz(col j), so a single heavy column inflates
@@ -41,7 +42,7 @@ documented follow-up for power-law datasets.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Tuple, Union
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -57,10 +58,17 @@ class DenseSlab(NamedTuple):
 
 
 class SparseSlab(NamedTuple):
-    """Padded-CSC slab: per bundle feature, its nonzero rows/values."""
+    """Padded-CSC slab: per bundle feature, its nonzero rows/values.
+
+    labels: the labels at `rows`, taken from the problem's per-column
+    label slab (`L1Problem.col_y`) by `L1Problem.gather_slab`; None for
+    a slab the design gathered alone, whose labels
+    `L1Problem.bundle_grad_hess` gathers from y at `rows`.
+    """
     rows: Array        # (P, k_max) int32; sentinel == n_samples at padding
     vals: Array        # (P, k_max) float; 0 at padding
     valid: Array       # (P,) bool
+    labels: Optional[Array] = None   # (P, k_max), or None
 
 
 class SlabSupport(NamedTuple):
@@ -103,7 +111,9 @@ class DesignMatrix:
     rmatvec(u)           -> (n,)  X^T @ u
     column_norms_sq()    -> (n,)  diag(X^T X)
     gather_slab(idx)     -> Slab  for a (P,) bundle with sentinel == n
-    slab_grad_hess(...)  -> (g, h) raw bundle reductions (no l2 / floor)
+    slab_grad_hess(...)  -> (g, h) raw bundle reductions (no l2 / floor);
+                            padded CSC: `slab_grad_hess_gathered`, from
+                            factors already laid out as the slab
     slab_matvec(...)     -> (s,)  X_B @ d_B (dense margins delta)
     """
 
@@ -273,11 +283,14 @@ class PaddedCSCDesign(DesignMatrix):
             valid[:, None].astype(self.col_vals.dtype)
         return SparseSlab(rows=rows, vals=vals, valid=valid)
 
-    def slab_grad_hess(self, slab: SparseSlab, u: Array, v: Array):
-        """Masked segment reductions over the padded column layout."""
+    def slab_grad_hess_gathered(self, slab: SparseSlab, ug: Array,
+                                vg: Array):
+        """Masked segment reductions over the padded column layout, from
+        u/v already laid out as the slab, (P, k_max): the k-order sums
+        that the full and support scopes share, so they agree bitwise
+        wherever their addends do (padding vals are 0, so whatever u/v
+        hold there adds nothing)."""
         vals = slab.vals.astype(self.acc_dtype)
-        ug = jnp.take(u, slab.rows, mode="fill", fill_value=0)
-        vg = jnp.take(v, slab.rows, mode="fill", fill_value=0)
         g = jnp.sum(ug * vals, axis=1)
         h = jnp.sum(vg * jnp.square(vals), axis=1)
         return g, h
@@ -302,19 +315,16 @@ class PaddedCSCDesign(DesignMatrix):
 
     def slab_grad_hess_support(self, slab: SparseSlab, pos: Array,
                                u_R: Array, v_R: Array):
-        """`slab_grad_hess` with u/v given only at the support rows.
+        """`slab_grad_hess_gathered` with u/v given only at the support
+        rows.
 
         u_R/v_R: (r_max,) factors evaluated at support order; pos maps
         each slab entry into them (always in-bounds, padding vals are 0),
         so the gather never touches the (s,)-sized vectors. Bitwise equal
         to the full-scope reduction: same addends in the same k-order.
         """
-        vals = slab.vals.astype(self.acc_dtype)
-        ug = jnp.take(u_R, pos)
-        vg = jnp.take(v_R, pos)
-        g = jnp.sum(ug * vals, axis=1)
-        h = jnp.sum(vg * jnp.square(vals), axis=1)
-        return g, h
+        return self.slab_grad_hess_gathered(slab, jnp.take(u_R, pos),
+                                            jnp.take(v_R, pos))
 
     def slab_matvec_support(self, slab: SparseSlab, pos: Array,
                             d: Array) -> Array:

@@ -231,7 +231,7 @@ def make_bundle_step(problem: L1Problem, cfg: PCDNConfig):
     def step(carry, idx):
         w, z = carry
         with jax.named_scope("pcdn.direction"):
-            slab = problem.design.gather_slab(idx)
+            slab = problem.gather_slab(idx)
             w_B, _ = B.gather_vec(w, idx)
             if cfg.use_kernels:
                 u = problem.grad_factor(z)

@@ -11,6 +11,12 @@ restrictions, so every solver runs unchanged on either layout.
 
 `elastic_net_l2` adds an optional (lambda2/2)||w||^2 smooth term (paper
 section 6 extension); it folds into the gradient/Hessian diagonals.
+
+`col_y` is the labels in the padded-CSC column layout, y[col_rows]
+(DESIGN.md section 7.1): `make_problem` builds it once for a sparse
+design, so a bundle's direction reads its labels with a P-row gather and
+gathers only the margins per slab entry; without it, the labels are
+gathered per slab entry like the margins.
 """
 from __future__ import annotations
 
@@ -32,25 +38,32 @@ Array = jax.Array
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class L1Problem:
-    """l1-regularized problem over a DesignMatrix backend. y: (s,) +-1."""
+    """l1-regularized problem over a DesignMatrix backend. y: (s,) +-1.
+
+    col_y: optional (n, k_max) labels at a padded-CSC design's col_rows,
+    1 at padding, in the design's dtype where that holds y exactly (see
+    `make_problem`); None for a dense design, a problem built directly,
+    and after `with_labels`.
+    """
 
     design: DesignMatrix
     y: Array
     c: float
     loss_name: str = "logistic"
     elastic_net_l2: float = 0.0
+    col_y: Optional[Array] = None
 
-    # -- pytree plumbing (design, y are leaves; scalars are static aux) ------
+    # -- pytree plumbing (design, y, col_y are leaves; scalars static aux) ---
     def tree_flatten(self):
-        return (self.design, self.y), (self.c, self.loss_name,
-                                       self.elastic_net_l2)
+        return (self.design, self.y, self.col_y), (self.c, self.loss_name,
+                                                   self.elastic_net_l2)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        design, y = children
+        design, y, col_y = children
         c, loss_name, l2 = aux
         return cls(design=design, y=y, c=c, loss_name=loss_name,
-                   elastic_net_l2=l2)
+                   elastic_net_l2=l2, col_y=col_y)
 
     # -- trace-time substitution ---------------------------------------------
     def with_c(self, c) -> "L1Problem":
@@ -64,8 +77,13 @@ class L1Problem:
         return dataclasses.replace(self, c=c)
 
     def with_labels(self, y: Array) -> "L1Problem":
-        """Replace labels (same design); used by the vmapped batch solver."""
-        return dataclasses.replace(self, y=y)
+        """Replace labels (same design); used by the vmapped batch solver.
+
+        Drops `col_y`: the batch solver swaps labels inside its traced
+        iteration, where rebuilding the (n, k_max) label slab would cost
+        a full gather every iteration; the direction then gathers the
+        labels at each slab entry, as for a problem without one."""
+        return dataclasses.replace(self, y=y, col_y=None)
 
     # -- basic accessors -----------------------------------------------------
     @property
@@ -151,20 +169,46 @@ class L1Problem:
             h = h + self.elastic_net_l2
         return g, jnp.maximum(h, HESSIAN_FLOOR)
 
+    def gather_slab(self, idx: Array) -> Slab:
+        """The design's slab for a (P,) bundle (sentinel == n); with
+        `col_y`, the padded-CSC slab also carries its labels, a P-row
+        gather like the one that fetches its values (a sentinel slot
+        reads label 1, as padding does)."""
+        slab = self.design.gather_slab(idx)
+        if self.col_y is None:
+            return slab
+        return slab._replace(labels=jnp.take(self.col_y, idx, axis=0,
+                                             mode="fill", fill_value=1))
+
     def bundle_grad_hess(self, z: Array, slab: Union[Slab, Array],
                          w_B: Array):
         """Gradient and Hessian diagonal restricted to a bundle slab.
 
-        slab: a DenseSlab/SparseSlab from design.gather_slab, or (legacy)
-        a raw dense (s, P) column block. Returns (g_B, h_B), each (P,).
+        slab: a DenseSlab/SparseSlab from `gather_slab`, or (legacy) a
+        raw dense (s, P) column block. Returns (g_B, h_B), each (P,).
         The reductions here are the compute hot-spot that the Pallas
         kernels fuse on TPU (DESIGN.md sections 3.1 / 7.3).
+
+        A padded-CSC slab gathers the margins at its entries and forms u
+        and v there, with the labels it carries (`gather_slab` with
+        `col_y`) or else gathered from y at its rows: one element gather
+        from an (s,) vector per slab entry, or two.
         """
-        u = self.grad_factor(z)
-        v = self.hess_factor(z)
-        if isinstance(slab, (DenseSlab, SparseSlab)):
-            g, h = self.design.slab_grad_hess(slab, u, v)
+        if isinstance(slab, SparseSlab):
+            z_g = jnp.take(z, slab.rows, mode="fill", fill_value=0)
+            if slab.labels is None:
+                y_g = jnp.take(self.y, slab.rows, mode="fill", fill_value=1)
+            else:
+                y_g = slab.labels.astype(self.y.dtype)
+            g, h = self.design.slab_grad_hess_gathered(
+                slab, self.grad_factor_at(z_g, y_g),
+                self.hess_factor_at(z_g, y_g))
+        elif isinstance(slab, DenseSlab):
+            g, h = self.design.slab_grad_hess(slab, self.grad_factor(z),
+                                              self.hess_factor(z))
         else:  # raw dense (s, P) array — legacy call sites and tests
+            u = self.grad_factor(z)
+            v = self.hess_factor(z)
             g = slab.T @ u
             h = jnp.square(slab).T @ v
         if self.elastic_net_l2:
@@ -255,8 +299,18 @@ def make_problem(
     # labels live with the solver state: f32 even under bf16 storage
     y = jnp.asarray(np.asarray(y),
                     dtype=jnp.promote_types(dtype, jnp.float32))
+    col_y = None
+    if isinstance(design, PaddedCSCDesign):
+        # one (n, k_max) gather at set-up; padding reads label 1 (finite
+        # factors at z = 0, and its value 0 adds nothing). Stored like
+        # col_vals where that holds every label exactly (+-1 in bf16), so
+        # it never takes more memory than the design's values.
+        y_col = y.astype(design.dtype)
+        if not bool(jnp.all(y_col.astype(y.dtype) == y)):
+            y_col = y
+        col_y = jnp.take(y_col, design.col_rows, mode="fill", fill_value=1)
     return L1Problem(design=design, y=y, c=float(c), loss_name=loss,
-                     elastic_net_l2=float(elastic_net_l2))
+                     elastic_net_l2=float(elastic_net_l2), col_y=col_y)
 
 
 def validation_accuracy(design, y, w) -> float:

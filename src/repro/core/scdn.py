@@ -58,7 +58,7 @@ def make_round(problem: L1Problem, cfg: SCDNConfig):
     def one_batch(carry, key):
         w, z = carry
         idx = jax.random.randint(key, (cfg.P_bar,), 0, n)  # with replacement
-        slab = problem.design.gather_slab(idx)
+        slab = problem.gather_slab(idx)
         w_B, _ = B.gather_vec(w, idx)
         g, h = problem.bundle_grad_hess(z, slab, w_B)
         d = newton_direction(g, h, w_B)

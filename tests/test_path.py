@@ -142,8 +142,8 @@ def test_batch_matches_looped_solves(problem, data):
         assert int(bres.nnz[i]) == int(jnp.sum(r.w != 0))
 
 
-def test_batch_per_problem_labels_and_seeds(problem, data):
-    X, y, _ = data
+def _batch_labels_match_solves(X, y, layout):
+    problem = make_problem(X, y, c=1.0, layout=layout)
     rng = np.random.default_rng(7)
     flip = rng.random((2, S)) < 0.2
     ys = np.stack([np.where(flip[i], -y, y) for i in range(2)])
@@ -151,11 +151,24 @@ def test_batch_per_problem_labels_and_seeds(problem, data):
     bres = solve_batch(problem, cfg, [1.0, 1.0], ys=ys, seeds=[11, 12])
     assert bool(np.all(np.asarray(bres.converged)))
     for i in range(2):
-        r = solve(make_problem(X, ys[i], c=1.0),
+        r = solve(make_problem(X, ys[i], c=1.0, layout=layout),
                   PCDNConfig(P=64, max_outer=200, tol_kkt=1e-4,
                              seed=11 + i))
         assert float(bres.objective[i]) == pytest.approx(r.objective,
                                                          rel=1e-4)
+
+
+def test_batch_per_problem_labels_and_seeds(data):
+    X, y, _ = data
+    _batch_labels_match_solves(X, y, "dense")
+
+
+def test_batch_per_problem_labels_padded_csc(data):
+    """Per-problem labels drop the padded-CSC problem's column labels
+    (`with_labels`) and the batch gathers its labels at each slab entry;
+    the single solves keep theirs and gather only the margins."""
+    X, y, _ = data
+    _batch_labels_match_solves(X, y, "padded_csc")
 
 
 def test_batch_warm_start_freeze_semantics(problem):
