@@ -317,8 +317,12 @@ def validation_accuracy(design, y, w) -> float:
     """Classification accuracy of sign(X_val @ w) against +-1 labels.
 
     `design` may be anything `as_design` accepts (dense array, CSR,
-    DesignMatrix), so held-out metrics never densify a sparse split.
-    Zero margins count as +1, matching data.synthetic.train_accuracy.
+    padded layout, DesignMatrix), so held-out metrics never densify a
+    sparse split. A host array or layout is uploaded on every call; a
+    DesignMatrix is used as it is, which is how a path sweep scores all
+    its points against one device design (`path.driver.run_path`
+    converts the split once). Zero margins count as +1, matching
+    data.synthetic.train_accuracy.
     """
     d = as_design(design)
     z = np.asarray(d.matvec(jnp.asarray(np.asarray(w), d.dtype)))

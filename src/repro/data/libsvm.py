@@ -41,6 +41,13 @@ class CSRMatrix:
         out[row_ids, self.indices] = self.data
         return out
 
+    def row_slice(self, start: int, stop: int) -> "CSRMatrix":
+        """Rows [start, stop) as their own CSRMatrix (a held-out split)."""
+        a, b = int(self.indptr[start]), int(self.indptr[stop])
+        return CSRMatrix(data=self.data[a:b], indices=self.indices[a:b],
+                         indptr=self.indptr[start:stop + 1] - a,
+                         shape=(stop - start, self.shape[1]))
+
     def sparsity(self) -> float:
         s, n = self.shape
         return 1.0 - self.nnz / float(s * n)

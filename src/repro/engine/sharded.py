@@ -588,7 +588,7 @@ def _is_csr_like(X) -> bool:
     return all(hasattr(X, a) for a in ("data", "indices", "indptr", "shape"))
 
 
-def _host_c_max(X, y, loss_name: str) -> float:
+def host_c_max(X, y, loss_name: str) -> float:
     """Analytic path start 1 / ||X^T phi'(0, y)||_inf from the host-side
     data (one rmatvec; matches L1Problem.c_max — DESIGN.md section 8.1)."""
     loss = get_loss(loss_name)
@@ -709,7 +709,7 @@ class ShardedBackend:
         self._s = int(X.shape[0])
         # eager: one host rmatvec now, so no reference to the (possibly
         # multi-GiB) host arrays survives construction
-        self._c_max = _host_c_max(X, y, cfg.loss_name)
+        self._c_max = host_c_max(X, y, cfg.loss_name)
         d_sz = int(np.prod([mesh.shape[a] for a in cfg.data_axes]))
 
         if layout == "dense":

@@ -9,6 +9,7 @@ backends from them, so the flags behave identically in both CLIs
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 
@@ -20,7 +21,9 @@ from repro import obs
 from repro.core import PCDNConfig, make_problem
 from repro.data import load_libsvm, paper_like
 from repro.engine import LocalBackend, ShardedBackend, ShardedPCDNConfig
+from repro.engine.sharded import host_c_max
 from repro.launch.mesh import make_host_mesh
+from repro.path.driver import sweep_backend
 
 # --dtype values -> storage dtype of the design values / serve bank
 # (solver state stays f32 either way — DESIGN.md section 12)
@@ -268,15 +271,15 @@ def finish_obs(args, meta: dict | None = None) -> None:
 
 def load_dataset(args, with_test: bool = False):
     """-> (X, y, Xte, yte, spec). File datasets have no test split and a
-    None spec; profile names go through `paper_like`. Honors the layout /
-    backend interplay: a padded_csc file load stays CSR for the sharded
-    placer (which re-packs per shard) and pre-packs padded-CSC locally.
+    None spec; profile names go through `paper_like`. A padded_csc file
+    load stays CSR: the local problem packs it (`make_problem`), the
+    sharded placer re-packs it per shard, and a caller may first split
+    its rows (`launch.path --val-frac`).
     """
     scale = args.scale
     if os.path.exists(args.dataset):
         if args.layout == "padded_csc":
-            file_layout = "csr" if args.backend == "sharded" \
-                else "padded_csc"
+            file_layout = "csr"
         else:
             file_layout = "dense"
         X, y = load_libsvm(args.dataset, layout=file_layout)
@@ -346,6 +349,24 @@ def make_backend(args, X, y, c: float, loss: str, outer=None):
     prob = make_problem(X, y, c=c, loss=loss, layout=args.layout,
                         dtype=DTYPES[getattr(args, "dtype", "fp32")])
     return LocalBackend(prob, build_pcdn_config(args), outer=outer), prob
+
+
+def make_path_backend(args, X, y, loss: str, cfg):
+    """The flags' backend for the path sweep `cfg` (a `PathConfig`), built
+    once with its compiled un-shrink threshold at the sweep's smallest
+    stop (`cfg.unshrink_tol`). local: the backend `run_path` builds
+    itself (`path.driver.sweep_backend`); sharded: c_max from the host
+    data sets the threshold before the mesh is placed. Returns (backend,
+    problem_or_None)."""
+    if args.backend == "sharded":
+        scfg = build_sharded_config(args, 1.0, loss)
+        scfg = dataclasses.replace(scfg, tol_kkt=cfg.unshrink_tol(
+            host_c_max(X, y, loss)))
+        mesh = make_host_mesh(args.data_parallel, args.model_parallel)
+        return ShardedBackend(X, y, mesh, scfg, layout=args.layout), None
+    prob = make_problem(X, y, c=1.0, loss=loss, layout=args.layout,
+                        dtype=DTYPES[getattr(args, "dtype", "fp32")])
+    return sweep_backend(prob, cfg), prob
 
 
 def load_warm_start(path: str, n: int, dtype) -> jnp.ndarray:

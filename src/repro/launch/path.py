@@ -20,29 +20,35 @@ import time
 import numpy as np
 
 from repro.utils.compile_cache import enable_compile_cache
+from repro.core import as_design
 from repro.core.problem import validation_accuracy
+from repro.data.libsvm import CSRMatrix
 from repro.launch import common
 from repro.serve import artifact as art
 from repro.path import PathConfig, PathPoint, PathResult, path_summary, \
     pick_best, problem_grid, run_path, solve_batch
 
 
+def _rows(X, start: int, stop: int):
+    """Rows [start, stop) of a dense array or a CSRMatrix."""
+    if isinstance(X, CSRMatrix):
+        return X.row_slice(start, stop)
+    return X[start:stop]
+
+
 def _load(args):
-    """-> (X, y, val_design, val_y) honoring --val-frac."""
-    X, y, _Xte, _yte, spec = common.load_dataset(args)
-    if spec is None:
-        if args.val_frac > 0:
-            # sparse row-split would need CSR re-packing; not wired yet
-            print("[path] --val-frac ignored for file datasets "
-                  "(no validation split, best-c pick disabled)")
-        return X, y, None, None
+    """-> (X, y, val_design, val_y) honoring --val-frac: the trailing
+    rows are held out, of a profile or a file dataset (a sparse file
+    loads as CSR and splits by row)."""
+    X, y, _Xte, _yte, _spec = common.load_dataset(args)
     if args.val_frac <= 0:
         return X, y, None, None
-    cut = max(1, int(round((1.0 - args.val_frac) * X.shape[0])))
-    if cut >= X.shape[0]:
+    s = X.shape[0]
+    cut = max(1, int(round((1.0 - args.val_frac) * s)))
+    if cut >= s:
         raise SystemExit(f"--val-frac {args.val_frac} leaves no "
-                         f"validation rows (s={X.shape[0]})")
-    return X[:cut], y[:cut], X[cut:], y[cut:]
+                         f"validation rows (s={s})")
+    return _rows(X, 0, cut), y[:cut], _rows(X, cut, s), y[cut:]
 
 
 def main(argv=None):
@@ -61,8 +67,14 @@ def main(argv=None):
                     help="sweep: sequential warm-started path; batch: "
                          "solve all grid points at once via vmap")
     ap.add_argument("--val-frac", type=float, default=0.2,
-                    help="held-out row fraction for the best-c pick "
-                         "(profile datasets; 0 disables)")
+                    help="held-out row fraction (the trailing rows) for "
+                         "the best-c pick; 0 disables")
+    ap.add_argument("--stop", default="absolute",
+                    choices=["absolute", "relative"],
+                    help="per-point KKT stop (--mode sweep): absolute "
+                         "stops every point at --tol; relative stops "
+                         "point i at --tol * KKT_{c_i}(0) = --tol * "
+                         "(c_i / c_max - 1), CDN's relative stop")
     common.add_solver_args(ap)
     common.add_backend_args(ap)
     ap.add_argument("--out", default=None, help="write path JSON here")
@@ -87,6 +99,9 @@ def main(argv=None):
     if args.mode == "batch" and args.backend == "sharded":
         ap.error("--mode batch is local-only (the vmapped batch solver "
                  "has no sharded execution backend yet)")
+    if args.mode == "batch" and args.stop != "absolute":
+        ap.error("--stop relative requires --mode sweep (the lockstep "
+                 "batch engine stops every problem at one tolerance)")
     if args.mode == "batch" and args.diag_out:
         ap.error("--diag-out requires --mode sweep (the lockstep batch "
                  "engine keeps no per-iteration history)")
@@ -95,10 +110,14 @@ def main(argv=None):
     X, y, Xval, yval = _load(args)
     common.setup_obs(args)
     solver = common.build_pcdn_config(args)
-    backend, prob = common.make_backend(args, X, y, 1.0, args.loss)
+    cfg = PathConfig(solver=solver, n_points=args.points, span=args.span,
+                     c_final=args.c_final, warm_start=not args.cold,
+                     stop=args.stop)
+    backend, prob = common.make_path_backend(args, X, y, args.loss, cfg)
     print(f"[path] dataset={args.dataset} s={X.shape[0]} "
           f"n={backend.n_features} c_max={backend.c_max():.5g} "
           f"points={args.points} mode={args.mode} shrink={args.shrink} "
+          f"stop={args.stop} "
           f"warm={not args.cold} backend={args.backend}")
 
     if args.mode == "batch":
@@ -107,10 +126,11 @@ def main(argv=None):
         t0 = time.perf_counter()
         bres = solve_batch(prob, solver, cs)
         total_s = time.perf_counter() - t0
+        val = None if Xval is None else as_design(Xval)
         points = []
         for i, c in enumerate(cs):
-            acc = (validation_accuracy(Xval, yval, np.asarray(bres.w[i]))
-                   if Xval is not None else None)
+            acc = (validation_accuracy(val, yval, np.asarray(bres.w[i]))
+                   if val is not None else None)
             p = PathPoint(c=float(c), objective=float(bres.objective[i]),
                           nnz=int(bres.nnz[i]), kkt=float(bres.kkt[i]),
                           n_outer=int(bres.n_outer[i]),
@@ -129,9 +149,6 @@ def main(argv=None):
                          total_seconds=total_s)
         payload = {"mode": "batch", **path_summary(res)}
     else:
-        cfg = PathConfig(solver=solver, n_points=args.points,
-                         span=args.span, c_final=args.c_final,
-                         warm_start=not args.cold)
         from repro import fault
         res = run_path(prob, cfg, val_design=Xval, val_y=yval,
                        verbose=True, backend=backend,
@@ -161,19 +178,20 @@ def main(argv=None):
             weights, res.cs, args.loss, metas=metas,
             provenance=art.solver_provenance(
                 solver="pcdn", dataset=args.dataset, backend=args.backend,
-                mode=args.mode, P=args.P, tol_kkt=args.tol, seed=args.seed,
+                mode=args.mode, P=args.P, tol_kkt=args.tol, stop=args.stop,
+                seed=args.seed,
                 shrink=bool(args.shrink), loss=args.loss,
                 dtype=args.dtype, best_index=res.best_index))
         art.save_model(args.save_model, family)
         print(f"[path] wrote model family ({len(family)} points) to "
               f"{args.save_model}")
     if args.diag_out:
-        from repro.core import as_design
         best = res.best
         diag_report = {
             "provenance": art.solver_provenance(
                 solver="pcdn", dataset=args.dataset, backend=args.backend,
-                mode=args.mode, P=args.P, tol_kkt=args.tol, seed=args.seed,
+                mode=args.mode, P=args.P, tol_kkt=args.tol, stop=args.stop,
+                seed=args.seed,
                 shrink=bool(args.shrink), loss=args.loss, dtype=args.dtype),
             "loss": args.loss, "n_features": int(backend.n_features),
             "objective": res.points[-1].objective if res.points else None,

@@ -235,6 +235,79 @@ def test_profiler_trace_holds_serve_spans(tmp_path):
         assert inner == ["serve.stack", "serve.score", "serve.respond"]
 
 
+def _sweep(problem, **kw):
+    from repro.path import PathConfig, run_path
+    cfg = PathConfig(solver=PCDNConfig(P=32, max_outer=30, tol_kkt=1e-2,
+                                       shrink=True),
+                     n_points=4, span=10.0, stop="relative")
+    return run_path(problem, cfg, **kw)
+
+
+def test_profiler_trace_nests_path_spans(tmp_path, problem, data):
+    """Each point's margin refresh, weight pull and held-out scoring sit
+    inside its path.point span, in order, around its engine iterations;
+    the point's host seconds outside the engine loop are recorded under
+    the profiler as without it."""
+    X, y, _ = data
+    _sweep(problem, val_design=X[:60], val_y=y[:60])   # compile first
+    with _profile(tmp_path):
+        res = _sweep(problem, val_design=X[:60], val_y=y[:60])
+    spans = _host_spans(tmp_path)
+    points = sorted(s for s in spans if s[0] == "path.point")
+    assert len(points) == len(res.points) == 4
+    for (_, a, b, line), p in zip(points, res.points):
+        inner = sorted((s for s in spans if s[0].startswith("path.")
+                        and s[0] != "path.point" and a <= s[1]
+                        and s[2] <= b), key=lambda s: s[1])
+        assert [s[0] for s in inner] == ["path.refresh", "path.pull",
+                                         "path.validate"]
+        assert {s[3] for s in inner} == {line}
+        outers = [s for s in spans if s[0] == "engine.outer"
+                  and a <= s[1] and s[2] <= b]
+        assert len(outers) == p.n_outer
+        assert inner[0][2] <= min(s[1] for s in outers)
+        assert inner[1][1] >= max(s[2] for s in outers)
+        assert 0.0 < p.between_s < p.seconds <= (b - a) * 1e-9 + 1e-3
+
+
+def test_path_host_seconds_recorded_without_a_profiler(monkeypatch,
+                                                       problem):
+    rec = _record_spans(monkeypatch, tracing=False)
+    res = _sweep(problem)
+    assert rec.names == []
+    for p in res.points:
+        assert 0.0 < p.between_s < p.seconds
+        assert len(p.n_active) == p.n_outer
+
+
+def test_checkpoint_without_new_point_fields_resumes(tmp_path, problem):
+    """A path checkpoint written before PathPoint had `between_s` and
+    `n_active` still resumes: its points read None there."""
+    import glob
+    from repro import fault
+    ref = _sweep(problem)
+    ck_dir = str(tmp_path / "p")
+    with pytest.raises(fault.InjectedCrash):
+        _sweep(problem, ckpt=fault.SolveCheckpointer(ck_dir, every=10),
+               fault_plan=fault.FaultPlan(crash_at_point=1,
+                                          crash_kind="exception"))
+    manifests = glob.glob(os.path.join(ck_dir, "step_*", "manifest.json"))
+    assert manifests
+    for path in manifests:
+        with open(path) as fh:
+            manifest = json.load(fh)
+        for p in manifest["extra"]["points"]:
+            del p["between_s"], p["n_active"]
+        with open(path, "w") as fh:
+            json.dump(manifest, fh)
+    res = _sweep(problem, ckpt=fault.SolveCheckpointer(ck_dir, every=10),
+                 resume=True)
+    assert [p.between_s for p in res.points[:2]] == [None, None]
+    assert [p.n_active for p in res.points[:2]] == [None, None]
+    assert all(p.between_s is not None for p in res.points[2:])
+    np.testing.assert_array_equal(res.weights, ref.weights)
+
+
 def test_queue_wait_adds_up():
     """queue_wait_s is the sum over rows of (flush start - submit): with
     one row per flush, every row's latency less its flush's compute."""

@@ -1,6 +1,8 @@
 """Regularization-path engine: grid/c_max analytics, warm-path-vs-cold
 equivalence, active-set shrinking, and the vmapped batch solver
 (DESIGN.md section 8)."""
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -54,10 +56,27 @@ def test_c_grid_geometry():
 
 # -- warm path == cold solves -------------------------------------------------
 
-def test_warm_path_matches_cold_solves(problem, data):
+def _kkt64(X, y, w, c):
+    """Float64 KKT violation of the logistic problem at w (dense X)."""
+    X = np.asarray(X, np.float64)
+    y = np.asarray(y, np.float64)
+    w = np.asarray(w, np.float64)
+    u = c * y * (1.0 / (1.0 + np.exp(-y * (X @ w))) - 1.0)
+    g = X.T @ u
+    v = np.where(w > 0, g + 1.0, np.where(w < 0, g - 1.0,
+                                          np.maximum(np.abs(g) - 1.0, 0.0)))
+    return float(np.max(np.abs(v)))
+
+
+@pytest.mark.parametrize("stop, shrink, tol", [
+    ("absolute", False, 1e-5),
+    # relative stops: 1e-6 * KKT_c(0) puts every point at 1.1e-6 .. 1.9e-5
+    ("relative", True, 1e-6)])
+def test_warm_path_matches_cold_solves(problem, data, stop, shrink, tol):
     X, y, _ = data
-    cfg = PathConfig(solver=PCDNConfig(P=64, max_outer=150, tol_kkt=1e-5),
-                     n_points=5, span=20.0)
+    cfg = PathConfig(solver=PCDNConfig(P=64, max_outer=150, tol_kkt=tol,
+                                       shrink=shrink),
+                     n_points=5, span=20.0, stop=stop)
     res = run_path(problem, cfg)
     assert all(p.converged for p in res.points)
     for i, c in enumerate(res.cs):
@@ -70,11 +89,15 @@ def test_warm_path_matches_cold_solves(problem, data):
             cold.objective, rel=1e-5)
 
 
-def test_path_records_and_best_pick(problem, data):
+@pytest.mark.parametrize("stop, shrink", [("absolute", False),
+                                          ("relative", True)])
+def test_path_records_and_best_pick(problem, data, stop, shrink):
     X, y, _ = data
     Xv, yv, _ = make_classification(120, N, sparsity=0.9, corr=0.3, seed=5)
-    cfg = PathConfig(solver=PCDNConfig(P=64, max_outer=80), n_points=4,
-                     span=10.0)
+    cfg = PathConfig(solver=PCDNConfig(P=64, max_outer=80, shrink=shrink,
+                                       tol_kkt=1e-3 if stop == "absolute"
+                                       else 1e-2),
+                     n_points=4, span=10.0, stop=stop)
     res = run_path(problem, cfg, val_design=Xv, val_y=yv)
     assert len(res.points) == 4 and res.weights.shape == (4, N)
     assert res.points[0].nnz == 0            # the c_max anchor is all-zero
@@ -82,6 +105,86 @@ def test_path_records_and_best_pick(problem, data):
     assert all(a is not None for a in accs)
     assert res.best_index is not None
     assert res.best.val_accuracy == max(accs)
+    # the pick is the argmax of float64 held-out accuracies, ties to the
+    # sparser model (then the smaller c)
+    acc64 = [float(np.mean(np.where(np.asarray(Xv, np.float64) @ w < 0,
+                                    -1.0, 1.0) == yv))
+             for w in np.asarray(res.weights, np.float64)]
+    np.testing.assert_allclose(accs, acc64, rtol=0, atol=1e-12)
+    nnz = [p.nnz for p in res.points]
+    assert res.best_index == min(range(4), key=lambda i: (-acc64[i], nnz[i],
+                                                          i))
+    for p in res.points:
+        assert 0.0 <= p.between_s <= p.seconds
+        assert len(p.n_active) == p.n_outer
+        assert all(0 < a <= N for a in p.n_active)
+
+
+def test_relative_stop_meets_each_point_stop_in_float64(problem, data):
+    """A warm, shrinking sweep under the relative stop: every point,
+    the c_max anchor among them, ends at a float64 KKT at or under its
+    own stop eps * KKT_{c_i}(0), and the anchor takes the next stop."""
+    X, y, _ = data
+    eps = 1e-2
+    cfg = PathConfig(solver=PCDNConfig(P=64, max_outer=200, tol_kkt=eps,
+                                       shrink=True),
+                     n_points=6, span=50.0, stop="relative")
+    c_max = problem.c_max()
+    tols = cfg.stops(c_max)
+    kkt0 = [max(c / c_max - 1.0, 0.0) for c in cfg.grid(c_max)]
+    np.testing.assert_allclose(tols[1:], eps * np.asarray(kkt0[1:]),
+                               rtol=1e-12)
+    assert kkt0[0] == 0.0 and tols[0] == tols[1] == tols.min()
+    res = run_path(problem, cfg)
+    assert all(p.converged for p in res.points)
+    assert res.points[0].nnz == 0 and res.points[0].n_outer >= 1
+    for i, p in enumerate(res.points):
+        assert p.kkt <= tols[i]
+        assert _kkt64(X, y, res.weights[i], p.c) <= tols[i]
+    assert min(min(p.n_active) for p in res.points) < N
+
+
+def test_shrink_consistency_holds_against_smallest_stop(problem):
+    """A shrinking backend must compile its un-shrink threshold at or
+    under the sweep's smallest stop: at it the sweep runs, above it the
+    sweep is refused."""
+    from repro.engine import LocalBackend
+    cfg = PathConfig(solver=PCDNConfig(P=64, max_outer=40, tol_kkt=1e-2,
+                                       shrink=True),
+                     n_points=6, span=10.0, stop="relative")
+    c_max = problem.c_max()
+    unshrink = cfg.unshrink_tol(c_max)
+    assert unshrink == cfg.stops(c_max).min() < cfg.solver.tol_kkt
+    at = LocalBackend(problem, dataclasses.replace(cfg.solver,
+                                                   tol_kkt=unshrink))
+    assert len(run_path(None, cfg, backend=at).points) == 6
+    above = LocalBackend(problem, cfg.solver)      # threshold = eps
+    with pytest.raises(ValueError, match="un-shrink"):
+        run_path(None, cfg, backend=above)
+    # without a backend the sweep builds its own at the smallest stop
+    assert len(run_path(problem, cfg).points) == 6
+
+
+def test_held_out_design_is_uploaded_once_per_sweep(problem, monkeypatch):
+    """run_path turns the held-out split into a device design once and
+    scores every point against it."""
+    from repro.core import design_matrix, problem as problem_mod
+    from repro.path import driver
+    Xv, yv, _ = make_classification(80, N, sparsity=0.9, corr=0.3, seed=6)
+    built = []
+    real = design_matrix.as_design
+
+    def counting(X, *a, **kw):
+        if not isinstance(X, design_matrix.DesignMatrix):
+            built.append(X)
+        return real(X, *a, **kw)
+    monkeypatch.setattr(driver, "as_design", counting)
+    monkeypatch.setattr(problem_mod, "as_design", counting)
+    cfg = PathConfig(solver=PCDNConfig(P=64, max_outer=40), n_points=5,
+                     span=10.0)
+    res = run_path(problem, cfg, val_design=Xv, val_y=yv)
+    assert all(p.val_accuracy is not None for p in res.points)
+    assert len(built) == 1 and built[0] is Xv
 
 
 # -- shrinking ----------------------------------------------------------------
@@ -195,6 +298,44 @@ def test_path_cli_smoke(tmp_path):
     assert out.exists() and (tmp_path / "path.json.weights.npy").exists()
     assert len(payload["points"]) == 4
     assert payload["best_c"] is not None
+
+
+def test_path_cli_splits_csr_file_and_stops_relative(tmp_path, monkeypatch):
+    """A sparse file dataset loads as CSR and its trailing --val-frac rows
+    are held out; --stop relative --shrink builds one training problem,
+    its backend once at the smallest stop, and sweeps to each point's
+    stop."""
+    from repro.data import save_libsvm
+    from repro.data.libsvm import CSRMatrix
+    from repro.launch import common
+    from repro.launch import path as launch_path
+    built = []
+    real = common.make_problem
+
+    def counting(X, *a, **kw):
+        built.append(X)
+        return real(X, *a, **kw)
+    monkeypatch.setattr(common, "make_problem", counting)
+    X, y, _ = make_classification(200, 96, sparsity=0.9, corr=0.3, seed=8)
+    svm = tmp_path / "d.svm"
+    save_libsvm(str(svm), CSRMatrix.from_dense(X), y)
+    payload = launch_path.main([
+        "--dataset", str(svm), "--layout", "padded_csc", "--val-frac",
+        "0.25", "--points", "4", "--span", "10", "--P", "16",
+        "--max-outer", "100", "--tol", "1e-2", "--stop", "relative",
+        "--shrink"])
+    points = payload["points"]
+    assert len(built) == 1 and isinstance(built[0], CSRMatrix)
+    assert built[0].shape == (150, 96)
+    assert payload["best_c"] is not None
+    assert all(p["converged"] for p in points)
+    # scored on the 50 held-out rows: counts of right answers
+    for p in points:
+        assert p["val_accuracy"] * 50 == pytest.approx(
+            round(p["val_accuracy"] * 50), abs=1e-9)
+    kkt0 = np.asarray([p["c"] for p in points]) / payload["c_max"] - 1.0
+    tols = 1e-2 * np.where(kkt0 > 0, kkt0, kkt0[1])
+    assert all(p["kkt"] <= t for p, t in zip(points, tols))
 
 
 def test_solve_cli_warm_start_roundtrip(tmp_path):
